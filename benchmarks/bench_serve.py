@@ -20,11 +20,7 @@ is 0 unless the store is a low-motion stream), p95 latency.
 from __future__ import annotations
 
 import collections
-import json
-import os
-import subprocess
-import sys
-import textwrap
+import hashlib
 import time
 
 import jax
@@ -32,10 +28,15 @@ import numpy as np
 
 from benchmarks import common
 from benchmarks.common import fmt_table
+from repro.compat import make_mesh
 from repro.core import distances
 from repro.core.engine import HistogramEngine, LikelihoodQuery, RegionQuery
 from repro.data import video_frames
-from repro.serve import AnalyticsService
+from repro.serve import (
+    AnalyticsService,
+    DistributedAnalyticsService,
+    sharded_engine_factory,
+)
 
 
 def _requests(num_requests: int, num_frames: int, hot: int, seed: int):
@@ -79,116 +80,93 @@ def _drive(svc: AnalyticsService, reqs, depth: int) -> float:
     return time.perf_counter() - t0
 
 
-# Mesh-scale curve (ISSUE 10): the same closed-loop traffic against
-# DistributedAnalyticsService at 1/2/4/8 forced host devices.  Each point
-# runs in a subprocess so the device count can differ per point without
-# disturbing the parent's single-device view; the subprocess reports one
-# `RESULT {json}` line with the wall time and a digest of every answer,
-# and the parent asserts the multi-device digests match the single-device
-# baseline (bit-exactness is the acceptance bar, throughput is the curve).
-_SCALE_BODY = r"""
-import hashlib, json, time, warnings
-warnings.filterwarnings("ignore")
-import numpy as np, jax
-
-from repro.core import distances
-from repro.core.engine import HistogramEngine, LikelihoodQuery, RegionQuery
-from repro.data import video_frames
-from repro.serve import (AnalyticsService, DistributedAnalyticsService,
-                         sharded_engine_factory)
-
-ndev = __NDEV__
-smoke = __SMOKE__
-assert len(jax.devices()) == ndev, (ndev, jax.devices())
-
-n_req = 48 if smoke else 240
-n_cams, per_cam = (4, 4) if smoke else (8, 8)
-h, w, bins = (96, 128, 16) if smoke else (240, 320, 16)
-
-# Independent camera streams: string refs do not chain (no predecessor),
-# so the consistent-hash router spreads them across replica groups.
-frames = {}
-for cam in range(n_cams):
-    for i, f in enumerate(video_frames(h, w, per_cam, seed=100 + cam)):
-        frames[f"cam{cam}/{i}"] = f
-refs = sorted(frames)
-rng = np.random.default_rng(3)
-target = np.ones(bins, np.float32)
-reqs = []
-for i in range(n_req):
-    ref = refs[int(rng.integers(0, len(refs)))]
-    if i % 3 == 2:
-        q = LikelihoodQuery(target, (24, 24), distances.intersection,
-                            stride=8)
-    else:
-        r0, c0 = int(rng.integers(0, 40)), int(rng.integers(0, 40))
-        q = RegionQuery(np.array([r0, c0, r0 + 23, c0 + 23]))
-    reqs.append((ref, q))
-
-if ndev == 1:
-    svc = AnalyticsService(HistogramEngine(bins, backend="jnp"), frames,
-                           cache_size=8, max_pending=256)
-else:
-    shape = {2: (1, 2), 4: (1, 4), 8: (2, 4)}[ndev]
-    mesh = jax.make_mesh(shape, ("data", "model"))
-    svc = DistributedAnalyticsService(
-        sharded_engine_factory(bins, backend="jnp"), frames,
-        mesh=mesh, replica_axis="data", cache_size=8, max_pending=256)
-
-svc.process(reqs[:2])  # warm the XLA compile cache
-svc.clear_cache()
-t0 = time.perf_counter()
-outs = svc.process(reqs)
-jax.block_until_ready(outs)
-wall = time.perf_counter() - t0
-
-digest = hashlib.blake2b(digest_size=16)
-for out in outs:
-    for leaf in jax.tree_util.tree_leaves(out):
-        digest.update(np.ascontiguousarray(np.asarray(leaf)).tobytes())
-print("RESULT " + json.dumps({"ndev": ndev, "wall_s": wall,
-                              "req_s": n_req / wall,
-                              "digest": digest.hexdigest()}))
-"""
-
+# Mesh-scale curve: the same closed-loop traffic against
+# DistributedAnalyticsService on the first 1/2/4/8 devices.  Every point
+# runs in this process on a mesh over ``jax.devices()[:ndev]`` (a chip
+# belongs to one process, so a child interpreter could not reach it), and
+# the multi-device answers must hash to the single-device baseline's
+# digest (bit-exactness is the acceptance bar, throughput is the curve).
+# On a CPU host ``benchmarks.run`` forces eight host devices before JAX
+# is imported, which rehearses the meshes.
+_SCALE_SHAPES = {2: (1, 2), 4: (1, 4), 8: (2, 4)}
 _SCALE_LAYOUT = {1: "single device (plain service)",
                  2: "1 group x 2-way bins",
                  4: "1 group x 4-way bins",
                  8: "2 groups x 4-way bins"}
 
 
+def _scale_point(ndev: int, smoke: bool) -> tuple[float, str]:
+    """(wall seconds, answer digest) of the scale traffic on ``ndev``
+    devices."""
+    n_req = 48 if smoke else 240
+    n_cams, per_cam = (4, 4) if smoke else (8, 8)
+    h, w, bins = (96, 128, 16) if smoke else (240, 320, 16)
+
+    # Independent camera streams: string refs do not chain (no
+    # predecessor), so the consistent-hash router spreads them across
+    # replica groups.
+    frames = {}
+    for cam in range(n_cams):
+        for i, f in enumerate(video_frames(h, w, per_cam, seed=100 + cam)):
+            frames[f"cam{cam}/{i}"] = f
+    refs = sorted(frames)
+    rng = np.random.default_rng(3)
+    target = np.ones(bins, np.float32)
+    reqs = []
+    for i in range(n_req):
+        ref = refs[int(rng.integers(0, len(refs)))]
+        if i % 3 == 2:
+            q = LikelihoodQuery(target, (24, 24), distances.intersection,
+                                stride=8)
+        else:
+            r0, c0 = int(rng.integers(0, 40)), int(rng.integers(0, 40))
+            q = RegionQuery(np.array([r0, c0, r0 + 23, c0 + 23]))
+        reqs.append((ref, q))
+
+    if ndev == 1:
+        svc = AnalyticsService(HistogramEngine(bins), frames,
+                               cache_size=8, max_pending=256)
+    else:
+        mesh = make_mesh(_SCALE_SHAPES[ndev], ("data", "model"),
+                         devices=jax.devices()[:ndev])
+        svc = DistributedAnalyticsService(
+            sharded_engine_factory(bins), frames,
+            mesh=mesh, replica_axis="data", cache_size=8, max_pending=256)
+
+    svc.process(reqs[:2])  # warm the XLA compile cache
+    svc.clear_cache()
+    t0 = time.perf_counter()
+    outs = svc.process(reqs)
+    jax.block_until_ready(outs)
+    wall = time.perf_counter() - t0
+
+    digest = hashlib.blake2b(digest_size=16)
+    for out in outs:
+        for leaf in jax.tree_util.tree_leaves(out):
+            digest.update(np.ascontiguousarray(np.asarray(leaf)).tobytes())
+    return wall, digest.hexdigest()
+
+
 def _scale_curve(smoke: bool) -> str:
-    """req/s vs forced host device count; asserts answers stay bit-exact."""
+    """req/s vs device count; asserts answers stay bit-exact."""
+    n_req = 48 if smoke else 240
     rows = []
-    digests: dict[int, str] = {}
+    base = None
     for ndev in (1, 2, 4, 8):
-        env = dict(os.environ,
-                   XLA_FLAGS=f"--xla_force_host_platform_device_count={ndev}",
-                   PYTHONPATH=os.environ.get("PYTHONPATH", "src"))
-        code = (_SCALE_BODY.replace("__NDEV__", str(ndev))
-                .replace("__SMOKE__", repr(smoke)))
-        proc = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                              env=env, capture_output=True, text=True,
-                              timeout=900)
-        if proc.returncode != 0:
-            raise RuntimeError(
-                f"scale point ndev={ndev} failed:\n{proc.stderr[-2000:]}")
-        res = next(json.loads(line[len("RESULT "):])
-                   for line in proc.stdout.splitlines()
-                   if line.startswith("RESULT "))
-        digests[ndev] = res["digest"]
-        exact = res["digest"] == digests[1]
-        if not exact:
+        if ndev > len(jax.devices()):
+            break
+        wall, digest = _scale_point(ndev, smoke)
+        base = base or digest
+        if digest != base:
             raise AssertionError(
                 f"ndev={ndev} answers diverge from the single-device "
-                f"baseline ({res['digest']} != {digests[1]})")
+                f"baseline ({digest} != {base})")
         common.TIMINGS.append({
-            "median_s": res["wall_s"], "min_s": res["wall_s"], "iters": 1,
+            "median_s": wall, "min_s": wall, "iters": 1,
             "label": f"serve_scale_ndev{ndev}",
         })
-        rows.append([ndev, _SCALE_LAYOUT[ndev], f"{res['req_s']:.1f}",
-                     f"{res['wall_s'] * 1e3:.0f} ms",
-                     "yes" if exact else "NO"])
+        rows.append([ndev, _SCALE_LAYOUT[ndev], f"{n_req / wall:.1f}",
+                     f"{wall * 1e3:.0f} ms", "yes"])
     return fmt_table(
         ["devices", "replica x shard layout", "req/s", "wall",
          "bit-exact vs 1 dev"], rows)
@@ -205,7 +183,7 @@ def run(quick: bool = False) -> str:
         for cache in (0, 8):
             reqs = _requests(n_req, n_frames, hot, seed=depth)
             svc = AnalyticsService(
-                HistogramEngine(bins, backend="jnp"), store,
+                HistogramEngine(bins), store,
                 cache_size=cache, max_pending=max(depth * 2, 4),
             )
             # warm the XLA compile cache, then start the measurement
@@ -234,9 +212,12 @@ def run(quick: bool = False) -> str:
          "runs/req", "updated", "p95 ms"],
         rows,
     )
-    out += ("\n\nmesh scaling (host 'devices' share one CPU core, so "
-            "req/s is about\ncorrectness of the sharded path under load, "
-            "not real speedup):\n")
+    out += "\n\nmesh scaling"
+    if jax.default_backend() == "cpu":
+        out += (" (host 'devices' share the CPU cores, so req/s is "
+                "about\ncorrectness of the sharded path under load, not "
+                "real speedup)")
+    out += ":\n"
     out += _scale_curve(quick or common.SMOKE)
     return out
 

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 import time
 
@@ -125,5 +126,22 @@ def main(argv=None):
     print("\nall benches complete")
 
 
+def _rehearse_meshes_on_cpu() -> None:
+    """On a CPU host, give the multi-device benches eight host devices.
+
+    Must run before JAX is imported: the flag is read once, at backend
+    start-up.  On an accelerator the real devices are used as they are."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        return
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            flags + " --xla_force_host_platform_device_count=8").strip()
+
+
 if __name__ == "__main__":
+    _rehearse_meshes_on_cpu()
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import distances
 from repro.core.engine import HistogramEngine, LikelihoodQuery
 from repro.core.region_query import likelihood_map, region_histogram
@@ -67,6 +68,7 @@ def main(argv=None):
                     help="stage-5 query burst against the AnalyticsService "
                          "(0 skips the serving demo)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     h, w = args.hw
 
     frames = video_frames(h, w, args.frames, seed=3)

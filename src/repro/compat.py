@@ -1,82 +1,26 @@
-"""Version-compat shims over the moving parts of the JAX API surface.
+"""The repo's one mesh constructor, for jax 0.9.
 
-The repo targets a range of jax releases (see README "Supported JAX
-versions"); three API moves matter to us:
-
-  * ``shard_map``: ``jax.experimental.shard_map.shard_map`` (<= 0.5) ->
-    ``jax.shard_map`` (>= 0.6).  The old entry point spells the
-    replication-check kwarg ``check_rep``; the new one ``check_vma``.
-    ``compat.shard_map`` accepts ``check_vma`` everywhere and translates.
-  * ``jax.sharding.AxisType``: introduced with explicit-sharding meshes
-    (jax >= 0.6).  Older ``jax.make_mesh`` has no ``axis_types`` kwarg at
-    all, and every axis behaves as Auto — so on old jax we simply drop
-    the argument.
-  * ``jax.make_mesh`` itself predates ``axis_types``; ``compat.make_mesh``
-    forwards it only when supported.
-
-Everything in the repo imports these names from here, never from jax
-directly, so a version bump is a one-file audit.
+``jax.make_mesh`` creates Explicit axes by default on this jax.  The
+sharded paths of the paper stack (``core/distributed.py``, ``ShardedH``
+in ``core/hsource.py``, the serving layer) were written for Auto axes,
+where the partitioner places every intermediate; under Explicit axes a
+plain gather on a row-sharded band must name its output sharding.  So
+every mesh in the repo, tests and benches included, is built here with
+Auto axis types — never by ``jax.make_mesh`` directly.
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-if hasattr(jax, "shard_map"):  # jax >= 0.6: top-level, takes check_vma
-    _shard_map_impl = jax.shard_map
-else:  # jax <= 0.5: experimental, takes check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SHARD_MAP_PARAMS = frozenset(
-    inspect.signature(_shard_map_impl).parameters
-)
+from jax.sharding import AxisType
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """``jax.shard_map`` with the modern (check_vma) spelling on any jax.
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """A mesh with Auto axes over ``devices`` (default: all of them).
 
-    ``check_vma`` maps onto the legacy ``check_rep`` kwarg when running on
-    a jax whose shard_map predates the rename.  ``None`` means "library
-    default" on either version.
-    """
-    if check_vma is not None:
-        if "check_vma" in _SHARD_MAP_PARAMS:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in _SHARD_MAP_PARAMS:
-            kwargs["check_rep"] = check_vma
-        # else: the kwarg vanished entirely; the check is advisory — drop it.
-    return _shard_map_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
+    Pass ``devices=jax.devices()[:k]`` to serve on the first ``k`` chips
+    of a host from one process."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(AxisType.Auto,) * len(axis_names), devices=devices,
     )
-
-
-# ---------------------------------------------------------------------------
-# Mesh construction / AxisType
-# ---------------------------------------------------------------------------
-#: ``jax.sharding.AxisType.Auto`` when the running jax has explicit-sharding
-#: axis types, else ``None`` (old meshes are implicitly all-Auto).
-AXIS_TYPE_AUTO = getattr(jax.sharding, "AxisType", None)
-if AXIS_TYPE_AUTO is not None:
-    AXIS_TYPE_AUTO = AXIS_TYPE_AUTO.Auto
-
-_MAKE_MESH_PARAMS = frozenset(inspect.signature(jax.make_mesh).parameters)
-
-
-def make_mesh(axis_shapes, axis_names, **kwargs):
-    """``jax.make_mesh`` that tolerates jax versions without axis_types.
-
-    On jax >= 0.6 every axis is created as AxisType.Auto (matching the old
-    implicit behaviour) unless the caller passes ``axis_types`` explicitly;
-    on older jax the kwarg is dropped because Auto is the only behaviour.
-    """
-    if "axis_types" in _MAKE_MESH_PARAMS:
-        if "axis_types" not in kwargs and AXIS_TYPE_AUTO is not None:
-            kwargs["axis_types"] = (AXIS_TYPE_AUTO,) * len(axis_names)
-    else:
-        kwargs.pop("axis_types", None)
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)

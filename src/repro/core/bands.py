@@ -230,7 +230,7 @@ def iter_banded_ih(
 
     def step(band_img, carry):
         H_band = compute_fn(band_img, carry)
-        return H_band, H_band[..., -1, :]
+        return H_band, H_band[..., H_band.shape[-2] - 1, :]
 
     # Band slices are staged whenever a placement is known or prefetch is
     # requested.  ``device`` may be a single ``Device`` or a ``Sharding``:

@@ -202,7 +202,7 @@ def update_dense_ih(
                 if D_prev is not None:
                     carry = carry + D_prev
             slab = recompute(next_frame[..., r0:r1, :], carry)
-            D = slab[..., -1, :] - H[..., r1 - 1, :]
+            D = slab[..., slab.shape[-2] - 1, :] - H[..., r1 - 1, :]
             steps.append(D if D_prev is None else D - D_prev)
             slabs.append(slab)
             starts.append(r0)
@@ -219,7 +219,7 @@ def update_dense_ih(
         old_bottom = H[..., r1 - 1, :]
         if is_dirty:
             slab = recompute(next_frame[..., r0:r1, :], new_carry)
-            new_carry = slab[..., -1, :]
+            new_carry = slab[..., slab.shape[-2] - 1, :]
             delta = new_carry - old_bottom
         elif delta is None:
             new_carry = old_bottom          # untouched prefix of the frame
@@ -269,7 +269,7 @@ def update_banded_factory(
             if report.dirty[i]:
                 Hb = recompute(next_frame[..., band.r0:band.r1, :],
                                new_carry)
-                new_carry = Hb[..., -1, :]
+                new_carry = Hb[..., Hb.shape[-2] - 1, :]
                 delta = new_carry - band.carry
                 yield dataclasses.replace(band, H=Hb, carry=new_carry)
             elif delta is None:

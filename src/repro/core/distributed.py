@@ -36,11 +36,10 @@ The exclusive cross-device prefix is implemented two ways:
 from __future__ import annotations
 
 import jax.numpy as jnp
-from jax import lax
 import numpy as np
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.binning import PAD_BIN, bin_indices
 from repro.core.scans import apply_carry
 from repro.kernels.ops import integral_histogram
@@ -113,6 +112,19 @@ def replica_meshes(mesh: Mesh, replica_axis: str) -> list:
         devs = np.take(np.asarray(mesh.devices), i, axis=ax)
         out.append(Mesh(devs, rest) if rest else None)
     return out
+
+
+def replica_devices(mesh: Mesh, replica_axis: str) -> list:
+    """The devices of each replica group ``replica_meshes`` returns, in
+    the same order: one tuple per index on ``replica_axis`` (the whole
+    mesh as one group when the axis is absent)."""
+    devs = np.asarray(mesh.devices)
+    names = list(mesh.axis_names)
+    if replica_axis not in names:
+        return [tuple(devs.flat)]
+    ax = names.index(replica_axis)
+    return [tuple(np.ravel(np.take(devs, i, axis=ax)))
+            for i in range(mesh.shape[replica_axis])]
 
 
 def bin_sharded_ih(
@@ -198,7 +210,7 @@ def spatial_sharded_ih(
         local_h = integral_histogram(
             idx, local_bins, method=method, backend=backend, value_range=None,
         )
-        carry = local_h[:, -1, :]                            # (b_local, w)
+        carry = local_h[:, local_h.shape[1] - 1, :]         # (b_local, w)
         prefix = exclusive_axis_scan(carry, row_axis, d_rows, scan_impl)
         return local_h + prefix[:, None, :]
 

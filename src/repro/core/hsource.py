@@ -591,7 +591,7 @@ def _rows_gather(mesh, kind, lead, bin_axis, row_axis, local_h):
     import jax
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
 
     if kind == "bin":
         fn = shard_map(
@@ -628,7 +628,7 @@ def _region_sharded(mesh, h_lead, rects_ndim, bin_axis):
     """Jitted (H, rects) -> per-bin-shard region histograms (bin kind)."""
     import jax
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
 
     fn = shard_map(
         lambda h_local, r: rq.region_histogram(h_local, r),

@@ -68,7 +68,7 @@ class DoubleBufferedExecutor:
         self.fn = fn
         self.depth = depth
         self.batch_size = batch_size
-        self.device = device or jax.devices()[0]
+        self.device = device
 
     def _runtime(self) -> FrameRuntime:
         return FrameRuntime(

@@ -90,11 +90,11 @@ def stage_stream(items: Iterable, size: int = 2, device=None) -> Iterator:
     the consumer's hands.
 
     ``device`` is any ``jax.device_put`` placement: a single ``Device``
-    (default: the first device) or a ``jax.sharding.Sharding`` — a
+    (default: the current default device, which ``jax.default_device``
+    sets per thread) or a ``jax.sharding.Sharding`` — a
     ``NamedSharding`` lays each staged item out across its mesh, which is
     how the sharded band streams commit their slices to the layout their
     shard_map expects instead of bouncing through one device."""
-    device = device if device is not None else jax.devices()[0]
     queue: collections.deque = collections.deque()
     for item in items:
         queue.append(jax.device_put(item, device))
@@ -271,7 +271,9 @@ class FrameRuntime:
             if adaptive else None
         )
         self.carry_in = carry_in
-        self.device = device if device is not None else jax.devices()[0]
+        # None stages onto the current default device, so a serving
+        # replica pinned with ``jax.default_device`` stays on its chip.
+        self.device = device
         self.stage_inputs = stage_inputs
         self.stage_ahead = stage_ahead
         self.block = block

@@ -117,7 +117,7 @@ def _hscan_kernel(idx_ref, out_ref, row_carry, *, bin_block, use_mxu):
     hs = _row_scan_mxu(mask) if use_mxu else jnp.cumsum(mask, axis=2)
     rc = jnp.where(iw == 0, 0.0, row_carry[...])           # (BIN_BLOCK, TH)
     hs = hs + rc[:, :, None]
-    row_carry[...] = hs[:, :, -1]
+    row_carry[...] = hs[:, :, tw - 1]
     out_ref[0] = hs
 
 
@@ -129,10 +129,11 @@ def _vscan_kernel(hh_ref, carry_ref, out_ref, col_carry, *, use_mxu):
     ih = pl.program_id(3)
 
     hs = hh_ref[0]                                         # (BIN_BLOCK, TH, TW)
+    th = hs.shape[1]
     vs = _col_scan_mxu(hs) if use_mxu else jnp.cumsum(hs, axis=1)
     cc = jnp.where(ih == 0, carry_ref[0], col_carry[...])  # (BIN_BLOCK, TW)
     vs = vs + cc[:, None, :]
-    col_carry[...] = vs[:, -1, :]
+    col_carry[...] = vs[:, th - 1, :]
     out_ref[0] = vs
 
 

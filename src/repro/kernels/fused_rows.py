@@ -26,6 +26,9 @@ matmul:
 strip, the in-strip offsets of its requested rows, padded with -1
 (matches no row, contributes zeros).  ``kp`` — the emission width — is
 the max rows any strip requests, padded to a sublane multiple of 8.
+The kernel reads it expanded to the one-hot ``sel`` operand
+``(nth, kp, tile)``: its ``(kp, tile)`` block obeys the TPU's 8x128
+block rule, which a ``(1, kp)`` row of the int32 table does not.
 The output is ``(n, nb_pad, nth * kp, w_pad)``: one kp-row slab per
 strip, written exactly once per grid step (the coverage discipline the
 dense kernel has), gathered back to request order on the host by the
@@ -57,7 +60,7 @@ from repro.kernels.specs import (
     Operand,
     Scratch,
 )
-from repro.kernels.wf_tis import _col_scan_mxu, _row_scan_mxu
+from repro.kernels.wf_tis import _col_scan_mxu, _left_matmul, _row_scan_mxu
 
 #: default emission width when a geometry declares none (the
 #: ``--check-kernels`` sweep runs plain KernelGeometry through here).
@@ -115,8 +118,8 @@ def kernel_specs(geom: KernelGeometry) -> tuple[KernelSpec, ...]:
     scan is the same wavefront.  What changes is the out-spec: block
     ``(1, bin_block, kp, tile)`` at index ``(f, bb, ih, iw)`` into the
     ``(n, nb_pad, nth * kp, w_pad)`` row-slab output (exactly-once
-    coverage, like the dense kernel), plus the per-strip ``slots`` table
-    as a third input broadcast over ``iw``/``bb``.
+    coverage, like the dense kernel), plus the per-strip one-hot ``sel``
+    table as a third input broadcast over ``iw``/``bb``.
     """
     kp = getattr(geom, "kp", DEFAULT_KP)
     n, nth, ntw, nbb = geom.n, geom.nth, geom.ntw, geom.nbb
@@ -145,8 +148,8 @@ def kernel_specs(geom: KernelGeometry) -> tuple[KernelSpec, ...]:
                         lambda f, ih, iw, bb: (f, ih, iw), dtype="int32"),
                 Operand("carry", (n, nbp, wp), (1, bb_blk, t),
                         lambda f, ih, iw, bb: (f, bb, iw)),
-                Operand("slots", (nth, kp), (1, kp),
-                        lambda f, ih, iw, bb: (ih, 0), dtype="int32"),
+                Operand("sel", (nth, kp, t), (1, kp, t),
+                        lambda f, ih, iw, bb: (ih, 0, 0)),
             ),
             out_specs=(
                 Operand("rows", (n, nbp, nth * kp, wp), (1, bb_blk, kp, t),
@@ -162,25 +165,10 @@ def kernel_specs(geom: KernelGeometry) -> tuple[KernelSpec, ...]:
     )
 
 
-def _select_rows_mxu(sel: jnp.ndarray, vs: jnp.ndarray) -> jnp.ndarray:
-    """out[b, j, :] = sum_o sel[j, o] * vs[b, o, :] — the one-hot row
-    gather as a batched MXU matmul (same shape discipline as the scan's
-    ``_col_scan_mxu``; dynamic sublane gathers are not a TPU primitive,
-    a 0/1 matmul is)."""
-    b = vs.shape[0]
-    sel_b = jnp.broadcast_to(sel, (b,) + sel.shape)
-    return jax.lax.dot_general(
-        sel_b,
-        vs,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
-
-
 def _fused_rows_kernel(
     idx_ref,      # (1, TH, TW) int32 bin indices (PAD_BIN outside the image)
     carry_ref,    # (1, BIN_BLOCK, TW) fp32 band carry-in (zeros = frame top)
-    slots_ref,    # (1, KP) int32 in-strip offsets of emitted rows (-1 empty)
+    sel_ref,      # (1, KP, TH) fp32 one-hot: slot j emits tile row o
     out_ref,      # (1, BIN_BLOCK, KP, TW) fp32 emitted corner rows
     row_carry,    # VMEM scratch (NBB, BIN_BLOCK, TH) — right-edge carries
     col_carry,    # VMEM scratch (NBB, BIN_BLOCK, W_PAD) — bottom-edge carries
@@ -208,7 +196,7 @@ def _fused_rows_kernel(
         hs = jnp.cumsum(mask, axis=2)
     rc = jnp.where(iw == 0, 0.0, row_carry[bb])            # (BIN_BLOCK, TH)
     hs = hs + rc[:, :, None]
-    row_carry[bb] = hs[:, :, -1]
+    row_carry[bb] = hs[:, :, tw - 1]
 
     if use_mxu:
         vs = _col_scan_mxu(hs)
@@ -217,19 +205,16 @@ def _fused_rows_kernel(
     cols = pl.dslice(iw * tile_w, tile_w)
     cc = jnp.where(ih == 0, carry_ref[0], col_carry[bb, :, cols])
     vs = vs + cc[:, None, :]
-    col_carry[bb, :, cols] = vs[:, -1, :]
+    col_carry[bb, :, cols] = vs[:, th - 1, :]
 
     # ---- the fused output stage: project the requested rows ----
     # vs is the final H on this tile (all dependencies are earlier raster
     # steps), so the strip's requested rows can be emitted right now.
-    off = slots_ref[0]                                     # (KP,)
-    kp = off.shape[0]
-    sel = (
-        off[:, None]
-        == jax.lax.broadcasted_iota(jnp.int32, (kp, th), 1)
-    ).astype(jnp.float32)                                  # (KP, TH)
+    sel = sel_ref[0]                                       # (KP, TH)
+    # out[b, j, :] = sum_o sel[j, o] * vs[b, o, :]: the one-hot row gather
+    # as a 0/1 matmul (dynamic sublane gathers are not a TPU primitive).
     if use_mxu:
-        out_ref[0] = _select_rows_mxu(sel, vs)
+        out_ref[0] = _left_matmul(sel, vs)
     else:
         out_ref[0] = jnp.sum(
             sel[None, :, :, None] * vs[:, None, :, :], axis=2
@@ -239,7 +224,7 @@ def _fused_rows_kernel(
 def fused_rows_pallas(
     idx: jnp.ndarray,
     num_bins: int,
-    slots: np.ndarray,
+    slots: jnp.ndarray,
     *,
     tile: int = 128,
     bin_block: int = 8,
@@ -269,12 +254,14 @@ def fused_rows_pallas(
         raise ValueError(
             f"{num_bins} bins not divisible by bin_block {bin_block}")
     nth, ntw, nbb = h // tile, w // tile, num_bins // bin_block
-    # analysis: allow-host-sync(slot table is host-built request metadata, never device data)
-    slots = np.asarray(slots, np.int32)
     if slots.ndim != 2 or slots.shape[0] != nth:
         raise ValueError(
             f"slots shape {slots.shape} != ({nth}, kp) for {nth} strips")
     kp = slots.shape[1]
+    # Expand to the one-hot selection the kernel multiplies by; -1 slots
+    # match no row and emit zeros.
+    sel = (jnp.asarray(slots)[:, :, None] == jnp.arange(tile)).astype(
+        jnp.float32)
     if carry is None:
         carry = jnp.zeros((n, num_bins, w), jnp.float32)
     if carry.shape != (n, num_bins, w):
@@ -299,7 +286,7 @@ def fused_rows_pallas(
             pl.BlockSpec(
                 (1, bin_block, tile), lambda f, ih, iw, bb: (f, bb, iw)
             ),
-            pl.BlockSpec((1, kp), lambda f, ih, iw, bb: (ih, 0)),
+            pl.BlockSpec((1, kp, tile), lambda f, ih, iw, bb: (ih, 0, 0)),
         ],
         out_specs=pl.BlockSpec(
             (1, bin_block, kp, tile), lambda f, ih, iw, bb: (f, bb, ih, iw)
@@ -308,7 +295,7 @@ def fused_rows_pallas(
             (n, num_bins, nth * kp, w), jnp.float32),
         scratch_shapes=scratch,
         interpret=interpret,
-    )(idx, carry.astype(jnp.float32), jnp.asarray(slots))
+    )(idx, carry.astype(jnp.float32), sel)
 
 
 def fused_geometry(

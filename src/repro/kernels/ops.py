@@ -208,6 +208,36 @@ def integral_histogram(
     )
 
 
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "num_bins", "tile", "bin_block", "use_mxu", "interpret",
+        "value_range",
+    ),
+)
+def _fused_rows_jit(frames, carry_in, slots, *, num_bins: int, tile: int,
+                    bin_block: int, use_mxu: bool, interpret: bool,
+                    value_range: int):
+    """The jit'd Pallas path of ``fused_corner_rows``: binning, padding,
+    the fused kernel and the crop as one program, traced once per shape.
+    The slot table is an operand, so requests that differ only in their
+    rows reuse the compiled kernel; called eagerly, the kernel would be
+    traced and compiled again on every call."""
+    w = frames.shape[-1]
+    idx = _pad_to(bin_indices(frames, num_bins, value_range), tile, tile,
+                  PAD_BIN)
+    nb_pad = num_bins + (-num_bins) % bin_block
+    carry = None
+    if carry_in is not None:
+        pad = [(0, 0), (0, nb_pad - num_bins), (0, (-w) % tile)]
+        carry = jnp.pad(carry_in.astype(jnp.float32), pad)
+    out = fused_rows_pallas(
+        idx, nb_pad, slots, tile=tile, bin_block=bin_block,
+        use_mxu=use_mxu, interpret=interpret, carry=carry,
+    )
+    return out[:, :num_bins, :, :w]
+
+
 def fused_corner_rows(
     image: jnp.ndarray,
     num_bins: int,
@@ -293,19 +323,13 @@ def fused_corner_rows(
     frames = frames[:, :h_cut]
 
     if backend == "pallas":
-        idx = bin_indices(frames, num_bins, value_range)
-        idx = _pad_to(idx, tile, tile, PAD_BIN)
-        nb_pad = num_bins + (-num_bins) % bin_block
-        slots, _, pos = slot_plan(rows, tile, idx.shape[-2])
-        carry = None
-        if carry_in is not None:
-            pad = [(0, 0), (0, nb_pad - num_bins), (0, (-w) % tile)]
-            carry = jnp.pad(carry_in.astype(jnp.float32), pad)
-        out = fused_rows_pallas(
-            idx, nb_pad, slots, tile=tile, bin_block=bin_block,
-            use_mxu=use_mxu, interpret=interpret, carry=carry,
+        slots, _, pos = slot_plan(rows, tile, bands_needed * tile)
+        out = _fused_rows_jit(
+            frames, carry_in, jnp.asarray(slots), num_bins=num_bins,
+            tile=tile, bin_block=bin_block, use_mxu=use_mxu,
+            interpret=interpret, value_range=value_range,
         )
-        R = out[:, :num_bins, pos, :w]
+        R = out[:, :, pos]
     else:
         # Stream tile-high bands through the scan, carry threaded between
         # dispatches; keep only the requested rows of each band.
@@ -318,7 +342,7 @@ def fused_corner_rows(
                 tile=tile, bin_block=bin_block, use_mxu=use_mxu,
                 interpret=interpret, value_range=value_range,
             )
-            carry = Hb[..., -1, :]
+            carry = Hb[..., Hb.shape[-2] - 1, :]
             local = rows[(rows >= b * tile) & (rows < (b + 1) * tile)]
             if local.size:
                 kept.append(Hb[..., local - b * tile, :])
@@ -369,30 +393,41 @@ def delta_apply(
     if backend not in ("auto", "pallas", "jnp"):
         raise ValueError(f"unknown backend {backend!r}")
     squeeze = H.ndim == 3
-    slab = H[None] if squeeze else H
-    d = delta[None] if squeeze and delta.ndim == 2 else delta
-    n, nb, h, w = slab.shape
-    if d.shape != (n, nb, w):
+    n, nb, h, w = (1,) + tuple(H.shape) if squeeze else H.shape
+    d_shape = (1,) + tuple(delta.shape) if squeeze and delta.ndim == 2 \
+        else tuple(delta.shape)
+    if d_shape != (n, nb, w):
         raise ValueError(
             f"delta shape {delta.shape} incompatible with {(n, nb, w)} "
             "(frames, num_bins, width)")
     if backend == "auto":
         backend = "pallas" if _on_tpu() else "jnp"
+    return _delta_apply_jit(H, delta, backend=backend, tile=tile,
+                            bin_block=bin_block, interpret=interpret)
 
+
+@functools.partial(
+    jax.jit, static_argnames=("backend", "tile", "bin_block", "interpret"))
+def _delta_apply_jit(H, delta, *, backend: str, tile: int, bin_block: int,
+                     interpret: bool):
+    """The jit'd core of ``delta_apply``: one program, so the reshapes,
+    padding and cropping around the kernel cost no copies of the slab."""
+    squeeze = H.ndim == 3
+    slab = H[None] if squeeze else H
+    d = delta.reshape((slab.shape[0],) + delta.shape[-2:])
     if backend == "jnp":
         out = slab + d[..., None, :]
-    else:
-        nb_pad = nb + (-nb) % bin_block
-        pad_b = [(0, 0), (0, nb_pad - nb)]
-        slab_p = jnp.pad(
-            _pad_to(slab.astype(jnp.float32), tile, tile, 0.0),
-            pad_b + [(0, 0), (0, 0)])
-        d_p = jnp.pad(d.astype(jnp.float32),
-                      pad_b + [(0, (-w) % tile)])
-        out = delta_apply_pallas(
-            slab_p, d_p, tile=tile, bin_block=bin_block,
-            interpret=interpret,
-        )[:, :nb, :h, :w]
+        return out[0] if squeeze else out
+    n, nb, h, w = slab.shape
+    nb_pad = nb + (-nb) % bin_block
+    pad_b = [(0, 0), (0, nb_pad - nb)]
+    slab_p = jnp.pad(
+        _pad_to(slab.astype(jnp.float32), tile, tile, 0.0),
+        pad_b + [(0, 0), (0, 0)])
+    d_p = jnp.pad(d.astype(jnp.float32), pad_b + [(0, (-w) % tile)])
+    out = delta_apply_pallas(
+        slab_p, d_p, tile=tile, bin_block=bin_block, interpret=interpret,
+    )[:, :nb, :h, :w]
     return out[0] if squeeze else out
 
 

@@ -116,7 +116,10 @@ def _tril_ones(n: int, dtype=jnp.float32):
 
 
 def _row_scan_mxu(x: jnp.ndarray) -> jnp.ndarray:
-    """Inclusive cumsum along the last axis via MXU: X @ triu(1)."""
+    """Inclusive cumsum along the last axis via MXU: X @ triu(1).
+
+    Only for 0/1 operands (the one-hot mask): those are exact in the
+    single bf16 pass the TPU makes at default precision."""
     tw = x.shape[-1]
     return jax.lax.dot_general(
         x,
@@ -127,20 +130,34 @@ def _row_scan_mxu(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def _col_scan_mxu(x: jnp.ndarray) -> jnp.ndarray:
-    """Inclusive cumsum along axis -2 via MXU: tril(1) @ X (batched).
+    """Inclusive cumsum along axis -2 via MXU: tril(1) @ X per bin.
 
-    out[b, i, j] = sum_r tril[i, r] * x[b, r, j] — expressed as a batched
-    dot_general (tril broadcast over the bin-block batch) so the result
-    keeps (batch, row, col) layout without a post-transpose.
+    out[b, i, j] = sum_r tril[i, r] * x[b, r, j] — one 2-D matmul per bin
+    of the block, stacked, so the result keeps (batch, row, col) layout
+    without a post-transpose.  A batched dot_general over a broadcast
+    tril is the same arithmetic, but Mosaic aborts on a later broadcast
+    add to its result.
     """
-    b, th = x.shape[0], x.shape[-2]
-    tril = jnp.broadcast_to(_tril_ones(th, x.dtype), (b, th, th))
-    return jax.lax.dot_general(
-        tril,
-        x,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
+    return _left_matmul(_tril_ones(x.shape[-2], x.dtype), x)
+
+
+def _left_matmul(a: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """out[b] = a @ x[b] for every b along the leading axis of ``x``.
+
+    At ``HIGHEST`` precision: ``x`` holds counts up to the frame width or
+    area, and the TPU's default single bf16 pass holds integers exactly
+    only up to 256, which on a v5e got most entries of a 640-column scan
+    wrong.  ``HIGHEST`` keeps f32 operands exact (to 2^24)."""
+    return jnp.stack([
+        jax.lax.dot_general(
+            a,
+            x[b],
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+        for b in range(x.shape[0])
+    ])
 
 
 def _wf_tis_kernel(
@@ -178,7 +195,7 @@ def _wf_tis_kernel(
     # also resets it at every new frame, since the raster restarts there.
     rc = jnp.where(iw == 0, 0.0, row_carry[bb])            # (BIN_BLOCK, TH)
     hs = hs + rc[:, :, None]
-    row_carry[bb] = hs[:, :, -1]                           # new right edge
+    row_carry[bb] = hs[:, :, tw - 1]                       # new right edge
 
     # ---- vertical scan within the tile ----
     if use_mxu:
@@ -194,7 +211,7 @@ def _wf_tis_kernel(
     cols = pl.dslice(iw * tile_w, tile_w)
     cc = jnp.where(ih == 0, carry_ref[0], col_carry[bb, :, cols])
     vs = vs + cc[:, None, :]
-    col_carry[bb, :, cols] = vs[:, -1, :]                  # new bottom edge
+    col_carry[bb, :, cols] = vs[:, th - 1, :]              # new bottom edge
 
     out_ref[0] = vs
 

@@ -3,9 +3,8 @@
 A FUNCTION, not a module constant — importing this module must never touch
 jax device state (smoke tests see 1 device; only dryrun forces 512).
 
-Mesh construction goes through repro.compat.make_mesh so the axis_types
-handling (jax.sharding.AxisType only exists on jax >= 0.6) stays in one
-place.
+Mesh construction goes through repro.compat.make_mesh, which gives
+every axis the Auto type the sharded code was written for.
 """
 
 from __future__ import annotations

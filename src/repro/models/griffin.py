@@ -156,7 +156,7 @@ def _rglru_seq_parallel(u, p, chunk: int, mesh, rules, h0=None):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     seq_ax = rules.present(mesh, rules.tp_axes)[0]
     batch_axes = rules.present(mesh, rules.batch_axes)

@@ -164,7 +164,7 @@ def ssd_seq_parallel(xh, dt, A, Bm, Cm, chunk: int, mesh, rules, h0=None):
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     seq_ax = rules.present(mesh, rules.tp_axes)[0]
     batch_axes = rules.present(mesh, rules.batch_axes)

@@ -8,7 +8,9 @@ One `AnalyticsService` per frame-parallel **replica group**
 strips over the group's submesh exactly as a single-service deployment
 would over the whole mesh.  A group whose submesh is one device gets a
 plain single-device engine (``engine_factory(None)``), which keeps the
-PR 9 incremental video-delta path alive — mesh plans recompute whole.
+PR 9 incremental video-delta path alive — mesh plans recompute whole —
+and its service is pinned to that group's device, so four one-chip
+replicas compute on four chips, not all on the first.
 
 On top of the per-group services this facade owns exactly three things:
 
@@ -140,10 +142,13 @@ class DistributedAnalyticsService:
             raise ValueError("pass mesh or num_replicas, not both")
         if mesh is None:
             groups: list = [None] * (num_replicas or 1)
+            devices: list = [None] * len(groups)
         else:
-            from repro.core.distributed import replica_meshes
+            from repro.core.distributed import replica_devices, \
+                replica_meshes
 
             groups = replica_meshes(mesh, replica_axis)
+            devices = replica_devices(mesh, replica_axis)
         if max_pending < 1:
             raise ValueError("max_pending must be >= 1")
         n = len(groups)
@@ -152,18 +157,19 @@ class DistributedAnalyticsService:
             predecessor if predecessor is not None else _int_predecessor
         )
         self.replicas: list[AnalyticsService] = []
-        for sub in groups:
-            if sub is not None and _mesh_devices(sub) == 1:
-                # A 1-device submesh plans exactly like no mesh but
-                # disables the incremental path; hand the factory None
-                # so single-device groups keep video-delta updates.
-                sub = None
+        for sub, devs in zip(groups, devices):
+            device = None
+            if mesh is not None and len(devs) == 1:
+                # A 1-device group plans exactly like no mesh (a mesh
+                # would disable the incremental path), so the factory
+                # gets None and the service is pinned to the device.
+                sub, device = None, devs[0]
             self.replicas.append(
                 AnalyticsService(
                     engine_factory(sub), frames,
                     cache_size=cache_size, cache_bytes=per_bytes,
                     max_pending=max_pending, max_coalesce=max_coalesce,
-                    predecessor=predecessor,
+                    predecessor=predecessor, device=device,
                 )
             )
         self.max_pending = max_pending
@@ -328,13 +334,6 @@ class DistributedAnalyticsService:
             r.clear_cache()
         with self._lock:
             self._routes.clear()
-
-
-def _mesh_devices(mesh) -> int:
-    n = 1
-    for v in dict(mesh.shape).values():
-        n *= v
-    return n
 
 
 def sharded_engine_factory(num_bins: int, **engine_kwargs) -> Callable:

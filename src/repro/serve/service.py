@@ -51,6 +51,7 @@ Two drivers share all of that logic:
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import queue
 import threading
@@ -58,6 +59,7 @@ import time
 from concurrent.futures import Future
 from typing import Any, Callable, Iterable, Mapping
 
+import jax
 import numpy as np
 
 
@@ -163,6 +165,10 @@ class AnalyticsService:
         ref follows, seeding the engine's incremental video-delta path
         when the predecessor's H is still cached.  Defaults to integer
         decrement; pass ``lambda ref: None`` to disable chaining.
+      device: the ``jax.Device`` every engine run and cached-source query
+        of this service executes on (``jax.default_device`` around each
+        batch); ``None`` keeps the process default.  One-chip replicas
+        of ``DistributedAnalyticsService`` each get their own.
     """
 
     # Shared mutable state and the methods that mutate it: writes to
@@ -182,6 +188,7 @@ class AnalyticsService:
         max_pending: int = 64,
         max_coalesce: int = 32,
         predecessor: Callable | None = None,
+        device=None,
     ):
         if cache_size < 0 or max_pending < 1 or max_coalesce < 1:
             raise ValueError(
@@ -190,6 +197,7 @@ class AnalyticsService:
         if cache_bytes is not None and cache_bytes < 0:
             raise ValueError("cache_bytes must be >= 0")
         self._engine = engine
+        self.device = device
         self._resolve = (
             frames.__getitem__ if hasattr(frames, "__getitem__") else frames
         )
@@ -301,6 +309,12 @@ class AnalyticsService:
             self.stats.coalesced += len(group) - 1
         return results
 
+    def _on_device(self):
+        """Pin this thread's dispatches to ``self.device`` (if any)."""
+        if self.device is None:
+            return contextlib.nullcontext()
+        return jax.default_device(self.device)
+
     def _process_batch(self, batch: list[_Pending]) -> list:
         """Coalesce a drained batch by frame_ref and answer every group.
         Results come back in submission order."""
@@ -310,7 +324,8 @@ class AnalyticsService:
         results: list = [None] * len(batch)
         for frame_ref, members in groups.items():
             group = [p for _, p in members]
-            outs = self._answer_group(frame_ref, group)
+            with self._on_device():
+                outs = self._answer_group(frame_ref, group)
             done = time.perf_counter()
             for (i, p), out in zip(members, outs):
                 results[i] = out

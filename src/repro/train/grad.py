@@ -91,7 +91,7 @@ def compressed_psum(partials: jnp.ndarray, mesh, axis: str) -> jnp.ndarray:
         sg = lax.all_gather(scale, axis)
         return jnp.tensordot(sg, qg.astype(jnp.float32), axes=((0,), (0,)))
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     return shard_map(
         inner, mesh=mesh,

@@ -9,6 +9,7 @@ import pytest
 
 import jax.numpy as jnp
 
+from repro.compat import make_mesh
 from repro.core import distances, scans
 from repro.core.bands import (
     BandPlan,
@@ -288,10 +289,9 @@ def test_map_bands_api_and_prefetch(rng):
 def test_banded_sharded_single_device(rng):
     """iter_banded_sharded_ih parity on a 1-device mesh (the 8-device run
     lives in test_distributed.py's subprocess tests)."""
-    import jax
     from repro.core.distributed import iter_banded_sharded_ih
 
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     img = _img(rng, 24, 16)
     full = integral_histogram(jnp.asarray(img), 8, backend="jnp")
     got = jnp.concatenate(

@@ -1,35 +1,18 @@
-"""repro.compat: the jax version shims must work on whatever jax is
-installed — these run single-device (shard_map over a 1-device mesh)."""
+"""repro.compat: the repo's mesh constructor, single-device."""
 
 import jax
-import jax.numpy as jnp
-import numpy as np
-from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_mesh, shard_map
+from repro.compat import make_mesh
 
 
 def test_make_mesh_single_device():
     m = make_mesh((1,), ("data",))
     assert m.shape == {"data": 1}
-    # axis_types explicitly passed is tolerated on every jax version
-    m2 = make_mesh((1,), ("data",), axis_types=None)
-    assert m2.shape == {"data": 1}
-
-
-def test_shard_map_check_vma_translation():
-    mesh = make_mesh((1,), ("x",))
-    x = jnp.arange(8.0)
-    out = shard_map(
-        lambda v: v * 2.0, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
-        check_vma=False,
-    )(x)
-    np.testing.assert_allclose(np.asarray(out), np.arange(8.0) * 2.0)
-    # default (None) must also work
-    out = shard_map(
-        lambda v: v + 1.0, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
-    )(x)
-    np.testing.assert_allclose(np.asarray(out), np.arange(8.0) + 1.0)
+    # every axis is Auto: the sharded paths were written for it
+    assert m.axis_types == (jax.sharding.AxisType.Auto,)
+    m2 = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    assert m2.shape == {"data": 1, "model": 1}
+    assert m2.axis_types == (jax.sharding.AxisType.Auto,) * 2
 
 
 def test_host_mesh_helper():

@@ -28,7 +28,8 @@ def test_distributed_integral_histograms():
     out = _run("""
         import warnings; warnings.filterwarnings("ignore")
         import numpy as np, jax, jax.numpy as jnp
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.compat import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         from repro.core.distributed import bin_sharded_ih, spatial_sharded_ih
         from repro.kernels.ref import integral_histogram_ref
         img = jnp.asarray(np.random.default_rng(1).integers(
@@ -117,7 +118,8 @@ def test_engine_sharded_parity_and_host_assembly():
             jnp.asarray(ref), (16, 24), 8))
 
         # bin-sharded plan (2x4 mesh, bins divide the model axis)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.compat import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         eng = HistogramEngine(16, backend="jnp", mesh=mesh)
         out = eng.run(img, [RegionQuery(rects),
                             SlidingWindowQuery((16, 24), 8)])
@@ -175,7 +177,8 @@ def test_expert_parallel_moe_matches_local():
         p = moe_params(jax.random.PRNGKey(1), cfg)
         x = jax.random.normal(jax.random.PRNGKey(2), (4, 8, 64)) * 0.1
         local, aux_l = moe_block(x, p, cfg)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.compat import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         with mesh, sharding_context(mesh):
             shard, aux_s = jax.jit(lambda x, p: moe_block(x, p, cfg))(x, p)
         err = float(jnp.max(jnp.abs(local - shard)))
@@ -193,7 +196,8 @@ def test_compressed_psum_accuracy():
         import warnings; warnings.filterwarnings("ignore")
         import numpy as np, jax, jax.numpy as jnp
         from repro.train.grad import compressed_psum
-        mesh = jax.make_mesh((8,), ("pod",))
+        from repro.compat import make_mesh
+        mesh = make_mesh((8,), ("pod",))
         parts = jnp.asarray(np.random.default_rng(0).normal(
             size=(8, 128)) * 1e-3)
         exact = jnp.sum(parts, 0)
@@ -225,7 +229,8 @@ def test_sharded_train_step_matches_unsharded():
                      jax.random.PRNGKey(2), (4, 16), 0, cfg.vocab_size)}
         _, m1 = jax.jit(step)(state, batch)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.compat import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = ShardingRules()
         with mesh, sharding_context(mesh, rules):
             st_shape = jax.eval_shape(lambda: init_state(
@@ -299,7 +304,8 @@ def test_distributed_service_parity_8dev():
         want = single.process(list(trace))
 
         # layout 1: 8 replica groups x 1 device — chain-pinned updates
-        mesh_r = jax.make_mesh((8,), ("data",))
+        from repro.compat import make_mesh
+        mesh_r = make_mesh((8,), ("data",))
         dist_r = DistributedAnalyticsService(
             sharded_engine_factory(bins, backend="jnp"), store,
             mesh=mesh_r, replica_axis="data")
@@ -312,10 +318,17 @@ def test_distributed_service_parity_8dev():
         per_updated = [p["updated"] for p in snap["replicas"]]
         assert sum(per_updated) == 4            # frames 1..4 updated...
         assert sum(1 for u in per_updated if u) == 1   # ...on ONE replica
+        # each one-device replica computes on its own device, not all
+        # on the first
+        for r, dev in zip(dist_r.replicas, mesh_r.devices.flat):
+            assert r.device == dev
+            for ref in r.cached_frames:
+                assert r._cache[ref].H.devices() == {dev}, (ref, dev)
         print("replica-parallel parity OK", per_updated)
 
         # layout 2: 2 replica groups x 4-way bin shard
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.compat import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         dist_s = DistributedAnalyticsService(
             sharded_engine_factory(bins, backend="jnp"), store,
             mesh=mesh, replica_axis="data")

@@ -15,6 +15,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.compat import make_mesh
 from repro.core import distances
 from repro.core.bands import iter_banded_ih
 from repro.core.engine import (
@@ -98,7 +99,7 @@ def test_plan_grid_parity(rng, h, w, bins, budget_rows, batch, storage,
 def test_plan_grid_parity_sharded_single_device(rng, axis, expect_kind):
     """The sharded representations on a 1-device mesh (the 8-device runs
     live in test_distributed.py's subprocess tests)."""
-    mesh = jax.make_mesh((1,), (axis,))
+    mesh = make_mesh((1,), (axis,))
     img = _img(rng, 24, 16)
     eng = HistogramEngine(8, backend="jnp", mesh=mesh)
     full = _oracle(img, 8)
@@ -216,7 +217,7 @@ def test_plan_absorbs_auto_batch_size():
 
 
 def test_plan_validation_errors():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     with pytest.raises(ValueError, match="storage"):
         plan(WorkloadSpec(height=16, width=16, num_bins=4, mesh=mesh,
                           storage="uint16", backend="jnp"))
@@ -329,7 +330,7 @@ def test_spatial_open_stream_plans_but_map_frames_rejects(rng):
     """num_frames=None (open stream) is frames one at a time, so a
     spatial plan is legal; map_frames still rejects it with its own
     'streams dense' error rather than the stack message."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     p = plan(WorkloadSpec(height=16, width=16, num_bins=4, num_frames=None,
                           mesh=mesh, sharding="spatial", backend="jnp"))
     assert p.representation == "sharded"
@@ -520,7 +521,7 @@ def test_plan_mesh_layout_rendered_and_validated(rng):
     from repro.analysis import plancheck
     from repro.core.engine import MeshLayout, choose_layout
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     p = plan(WorkloadSpec(height=24, width=16, num_bins=8, num_frames=1,
                           backend="jnp", mesh=mesh))
     assert p.sharding == "bin"

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -111,6 +112,9 @@ def test_fused_pallas_call_matches_spec(monkeypatch, rng):
         return real(kernel, **kw)
 
     monkeypatch.setattr(pl, "pallas_call", spy)
+    # The Pallas path is jit'd: drop earlier traces of these shapes so
+    # this call traces the kernel (and meets the spy) again.
+    jax.clear_caches()
 
     frames = rng.integers(0, 256, (2, 20, 24), np.uint8)
     rows = np.asarray([1, 6, 7, 13, 19])

@@ -239,7 +239,8 @@ def test_sp_ssd_matches_single_device():
             jax.random.PRNGKey(1), (2, 64), 0, cfg.vocab_size)}
         ref, _, _ = api.forward(
             params, batch, dataclasses.replace(cfg, ssm_seq_parallel=False))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.compat import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         with mesh, sharding_context(mesh, ShardingRules()):
             sp, _, _ = jax.jit(lambda p, b: api.forward(p, b, cfg))(
                 params, batch)
@@ -276,7 +277,8 @@ def test_sp_rglru_matches_single_device():
             jax.random.PRNGKey(1), (2, 64), 0, cfg.vocab_size)}
         ref, _, _ = api.forward(
             params, batch, dataclasses.replace(cfg, rnn_seq_parallel=False))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.compat import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         with mesh, sharding_context(mesh, ShardingRules()):
             sp, _, _ = jax.jit(lambda p, b: api.forward(p, b, cfg))(
                 params, batch)

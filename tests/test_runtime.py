@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.compat import make_mesh
 from repro.core.bands import banded_integral_histogram, iter_banded_ih
 from repro.core.engine import HistogramEngine, auto_batch_size
 from repro.core.integral_histogram import IntegralHistogram
@@ -325,12 +326,11 @@ def test_stage_stream_accepts_a_sharding():
     """`device=` takes any jax.device_put placement — a NamedSharding
     commits each staged item to the mesh layout instead of one device
     (what removed the sharded-plan staging carve-out in bands)."""
-    import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core.runtime import stage_stream
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     ns = NamedSharding(mesh, P())
     items = [np.full((4, 4), i, np.float32) for i in range(3)]
     staged = list(stage_stream(iter(items), size=2, device=ns))
@@ -341,10 +341,9 @@ def test_stage_stream_accepts_a_sharding():
 
 
 def test_frame_runtime_stages_with_a_sharding(rng):
-    import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     ns = NamedSharding(mesh, P())
     seen = []
 

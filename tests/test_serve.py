@@ -10,6 +10,7 @@ import threading
 import numpy as np
 import pytest
 
+from repro.compat import make_mesh
 from repro.core import distances
 from repro.core.engine import (
     HistogramEngine,
@@ -391,12 +392,11 @@ def test_sharded_h_nbytes_tracks_storage_dtype():
     """Satellite: ShardedH.nbytes reports the real array footprint (the
     inherited planner estimate assumed 4-byte elements, so byte-aware
     cache eviction mis-charged sharded sources)."""
-    import jax
     import jax.numpy as jnp
 
     from repro.core.hsource import ShardedH
 
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     f32 = ShardedH(jnp.zeros((8, 16, 12), jnp.float32), mesh, kind="bin")
     assert f32.nbytes == 8 * 16 * 12 * 4
     u16 = ShardedH(jnp.zeros((8, 16, 12), jnp.uint16), mesh, kind="bin")
