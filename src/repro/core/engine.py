@@ -38,6 +38,7 @@ from typing import Iterable, Iterator
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import autotune
 from repro.core import delta as delta_mod
@@ -1025,15 +1026,48 @@ class HistogramEngine:
         'fused'
         >>> [float(v) for v in np.asarray(out.results[0]).ravel()]
         [16.0, 16.0, 16.0, 16.0]
+
+        Each stage is a ``jax.profiler.TraceAnnotation`` inside
+        ``engine.run`` (``representation``, ``incremental``):
+        ``engine.plan``, ``engine.validate``, ``engine.update`` or
+        ``engine.compute`` (the H dispatch, with a band stream's row
+        prefetch), and one ``engine.query`` (``kind``) per query.
         """
         queries = list(queries)
+        with TraceAnnotation("engine.run") as span:
+            with TraceAnnotation("engine.plan"):
+                p, prev_source, report = self._plan_run(frames, queries,
+                                                        prev)
+            span.set_metadata(representation=p.representation,
+                              incremental=int(p.incremental))
+            with TraceAnnotation("engine.validate"):
+                self._validate_or_raise(p, queries)
+            with TraceAnnotation("engine.update" if p.incremental
+                                 else "engine.compute"):
+                if p.incremental:
+                    source = self._update(prev_source, frames, report, p)
+                else:
+                    source = self.compute(frames, p)
+                target = source
+                if len(queries) > 1 and isinstance(source, BandedH):
+                    target = prefetch_rows(source, queries) or source
+            results = []
+            for q in queries:
+                with TraceAnnotation("engine.query", kind=type(q).__name__):
+                    results.append(q.apply(target))
+        return EngineResult(plan=p, source=source, results=results)
+
+    def _plan_run(self, frames, queries: list, prev):
+        """``run``'s plan: the spec with the queries' corner-row union,
+        dirty-band detection against ``prev``, and the (re-)plan.
+        Returns (plan, predecessor source, dirty report)."""
         spec = self.spec_for(np.shape(frames),
                              getattr(frames, "dtype", "uint8"))
         rows = _declared_rows(queries, spec.height, spec.width)
         if rows is not None:
             spec = dataclasses.replace(spec, query_rows=rows)
 
-        prev_frame = prev_source = report = None
+        prev_source = report = None
         if prev is not None:
             prev_frame, prev_source = prev
             if isinstance(prev_source, EngineResult):
@@ -1052,16 +1086,7 @@ class HistogramEngine:
             spec = dataclasses.replace(spec, dirty_fraction=None)
             p = plan(spec)
         self.last_plan = p
-        self._validate_or_raise(p, queries)
-        if p.incremental:
-            source = self._update(prev_source, frames, report, p)
-        else:
-            source = self.compute(frames, p)
-        target = source
-        if len(queries) > 1 and isinstance(source, BandedH):
-            target = prefetch_rows(source, queries) or source
-        results = [q.apply(target) for q in queries]
-        return EngineResult(plan=p, source=source, results=results)
+        return p, prev_source, report
 
     # -- streaming ----------------------------------------------------------
     def runtime_for(self, p: ExecutionPlan, step=None, *, depth: int = 2,

@@ -183,6 +183,7 @@ def cw_tis_pallas(
         out_shape=jax.ShapeDtypeStruct((n, num_bins, h, w), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bin_block, tile), jnp.float32)],
         interpret=interpret,
+        name="cw_tis_hscan",
     )(idx)
 
     out = pl.pallas_call(
@@ -202,5 +203,6 @@ def cw_tis_pallas(
         out_shape=jax.ShapeDtypeStruct((n, num_bins, h, w), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bin_block, tile), jnp.float32)],
         interpret=interpret,
+        name="cw_tis_vscan",
     )(hh, carry.astype(jnp.float32))
     return out[0] if squeeze else out

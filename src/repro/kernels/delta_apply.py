@@ -122,4 +122,5 @@ def delta_apply_pallas(
                                lambda f, bb, ih, iw: (f, bb, ih, iw)),
         out_shape=jax.ShapeDtypeStruct((n, nb, h, w), jnp.float32),
         interpret=interpret,
+        name="delta_apply",
     )(H.astype(jnp.float32), delta.astype(jnp.float32))

@@ -295,6 +295,7 @@ def fused_rows_pallas(
             (n, num_bins, nth * kp, w), jnp.float32),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="fused_rows",
     )(idx, carry.astype(jnp.float32), sel)
 
 

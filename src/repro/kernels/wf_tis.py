@@ -282,5 +282,6 @@ def wf_tis_pallas(
         out_shape=jax.ShapeDtypeStruct((n, num_bins, h, w), jnp.float32),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="wf_tis",
     )(idx, carry.astype(jnp.float32))
     return out[0] if squeeze else out

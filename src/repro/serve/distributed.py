@@ -157,7 +157,7 @@ class DistributedAnalyticsService:
             predecessor if predecessor is not None else _int_predecessor
         )
         self.replicas: list[AnalyticsService] = []
-        for sub, devs in zip(groups, devices):
+        for i, (sub, devs) in enumerate(zip(groups, devices)):
             device = None
             if mesh is not None and len(devs) == 1:
                 # A 1-device group plans exactly like no mesh (a mesh
@@ -170,6 +170,7 @@ class DistributedAnalyticsService:
                     cache_size=cache_size, cache_bytes=per_bytes,
                     max_pending=max_pending, max_coalesce=max_coalesce,
                     predecessor=predecessor, device=device,
+                    name=f"analytics-service-{i}",
                 )
             )
         self.max_pending = max_pending
@@ -294,13 +295,15 @@ class DistributedAnalyticsService:
 
     # -- introspection -------------------------------------------------------
     def snapshot(self) -> dict:
-        """Aggregate counters/rates + per-replica snapshots."""
+        """Aggregate counters/rates + per-replica snapshots.  The latency
+        percentiles are over each replica's most recent requests
+        (``service.LATENCY_WINDOW`` each), merged."""
         per = [r.stats.snapshot() for r in self.replicas]
         lat = np.sort(np.concatenate(
             [np.asarray(list(r.stats.latencies_s), np.float64)
              for r in self.replicas]
         )) if self.replicas else np.zeros(0)
-        done = len(lat)
+        n = len(lat)
         wall = time.perf_counter() - self._started_at
         agg: dict = {
             k: sum(p[k] for p in per)
@@ -314,11 +317,9 @@ class DistributedAnalyticsService:
         agg["hit"] = agg["cache_hits"]
         agg["cache_hit_rate"] = agg["cache_hits"] / max(agg["requests"], 1)
         agg["update_ratio"] = agg["updated"] / max(agg["engine_runs"], 1)
-        agg["requests_per_s"] = done / wall if wall > 0 else 0.0
-        agg["latency_p50_s"] = (
-            float(lat[int(0.50 * (done - 1))]) if done else 0.0)
-        agg["latency_p95_s"] = (
-            float(lat[int(0.95 * (done - 1))]) if done else 0.0)
+        agg["requests_per_s"] = agg["completed"] / wall if wall > 0 else 0.0
+        agg["latency_p50_s"] = float(lat[int(0.50 * (n - 1))]) if n else 0.0
+        agg["latency_p95_s"] = float(lat[int(0.95 * (n - 1))]) if n else 0.0
         agg["num_replicas"] = len(self.replicas)
         agg["routed_refs"] = routes
         agg["replicas"] = per

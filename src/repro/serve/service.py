@@ -31,10 +31,18 @@ This module adds the request-level scheduler on top:
     (``max_pending``); a full queue rejects with ``ServiceOverloaded``
     instead of growing without bound (Ehsan et al.'s
     resource-constrained serving posture: fail loudly, never thrash).
-  * **Stats** — per-request latency (p50/p95), throughput,
-    cache hit rate, coalescing ratio, engine-run count
-    (``service.stats.snapshot()``) — what benchmarks/bench_serve.py
-    reports.
+  * **Stats** — per-request latency (p50/p95 over the most recent
+    4096 requests), throughput, cache hit rate, coalescing ratio,
+    engine-run count (``service.stats.snapshot()``) — what
+    benchmarks/bench_serve.py reports.
+  * **Spans** — ``jax.profiler.TraceAnnotation`` at each boundary, on
+    the profiler's host line of the worker thread: ``service.wait``
+    (blocked on an empty queue), ``service.batch`` (one drained batch:
+    ``size``, ``wait_us`` = summed queue wait of its requests,
+    ``device``), ``service.group`` (one frame group: ``frame``,
+    ``outcome`` = hit / updated / recomputed / refetch) and
+    ``service.resolve`` (one call of the frame resolver).  They cost
+    about a microsecond each when no profiler session runs.
 
 Two drivers share all of that logic:
 
@@ -61,6 +69,11 @@ from typing import Any, Callable, Iterable, Mapping
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+#: latency samples ``ServiceStats`` keeps: percentiles cover the most
+#: recent requests, and a long-running service holds a bounded window.
+LATENCY_WINDOW = 4096
 
 
 class ServiceOverloaded(RuntimeError):
@@ -69,7 +82,8 @@ class ServiceOverloaded(RuntimeError):
 
 @dataclasses.dataclass
 class ServiceStats:
-    """Counters + latency samples; ``snapshot()`` derives the rates."""
+    """Counters + the latencies of the most recent ``LATENCY_WINDOW``
+    requests; ``snapshot()`` derives the rates."""
 
     requests: int = 0
     engine_runs: int = 0            # H computations (cache misses)
@@ -78,16 +92,22 @@ class ServiceStats:
     rejected: int = 0               # backpressure rejections
     updated: int = 0                # engine runs via incremental update
     recomputed: int = 0             # engine runs via full recompute
-    latencies_s: list = dataclasses.field(default_factory=list)
+    completed: int = 0              # requests answered
+    latencies_s: collections.deque = dataclasses.field(
+        default_factory=lambda: collections.deque(maxlen=LATENCY_WINDOW))
     started_at: float = dataclasses.field(default_factory=time.perf_counter)
 
     def observe(self, latency_s: float) -> None:
+        self.completed += 1
         self.latencies_s.append(latency_s)
 
     def snapshot(self) -> dict:
-        lat = np.sort(np.asarray(self.latencies_s, np.float64))
+        """Counters since start; the latency percentiles are over the
+        most recent ``LATENCY_WINDOW`` requests."""
+        lat = np.sort(np.asarray(list(self.latencies_s), np.float64))
         wall = time.perf_counter() - self.started_at
-        done = len(lat)
+        done = self.completed
+        n = len(lat)
         return {
             "requests": self.requests,
             "completed": done,
@@ -103,8 +123,8 @@ class ServiceStats:
             "hit": self.cache_hits,
             "update_ratio": self.updated / max(self.engine_runs, 1),
             "requests_per_s": done / wall if wall > 0 else 0.0,
-            "latency_p50_s": float(lat[int(0.50 * (done - 1))]) if done else 0.0,
-            "latency_p95_s": float(lat[int(0.95 * (done - 1))]) if done else 0.0,
+            "latency_p50_s": float(lat[int(0.50 * (n - 1))]) if n else 0.0,
+            "latency_p95_s": float(lat[int(0.95 * (n - 1))]) if n else 0.0,
         }
 
 
@@ -169,6 +189,8 @@ class AnalyticsService:
         of this service executes on (``jax.default_device`` around each
         batch); ``None`` keeps the process default.  One-chip replicas
         of ``DistributedAnalyticsService`` each get their own.
+      name: the worker thread's name, as thread dumps show it
+        (replicas are ``analytics-service-<i>``).
     """
 
     # Shared mutable state and the methods that mutate it: writes to
@@ -189,6 +211,7 @@ class AnalyticsService:
         max_coalesce: int = 32,
         predecessor: Callable | None = None,
         device=None,
+        name: str = "analytics-service",
     ):
         if cache_size < 0 or max_pending < 1 or max_coalesce < 1:
             raise ValueError(
@@ -198,6 +221,7 @@ class AnalyticsService:
             raise ValueError("cache_bytes must be >= 0")
         self._engine = engine
         self.device = device
+        self.name = name
         self._resolve = (
             frames.__getitem__ if hasattr(frames, "__getitem__") else frames
         )
@@ -229,9 +253,15 @@ class AnalyticsService:
                 _, dropped = self._cache.popitem(last=False)
                 total -= getattr(dropped, "nbytes", 0)
 
+    def _frame(self, frame_ref):
+        """The frame ``frame_ref`` names, from the resolver."""
+        with TraceAnnotation("service.resolve", frame=str(frame_ref)):
+            return self._resolve(frame_ref)
+
     def _source_for(self, frame_ref, queries):
-        """(source, results-or-None, hit): the cached HSource, or one
-        engine run answering ``queries`` directly on a miss."""
+        """(source, results-or-None, outcome): the cached HSource
+        (``"hit"``), or one engine run answering ``queries`` directly on
+        a miss (``"updated"`` or ``"recomputed"``)."""
         with self._lock:
             cached = self._cache.get(frame_ref)
             if cached is not None:
@@ -245,12 +275,12 @@ class AnalyticsService:
                 if prev_ref is not None:
                     prev_src = self._cache.get(prev_ref)
         if cached is not None:
-            return cached, None, True
-        frame = self._resolve(frame_ref)
+            return cached, None, "hit"
+        frame = self._frame(frame_ref)
         prev = None
         if prev_src is not None:
             try:
-                prev = (self._resolve(prev_ref), prev_src)
+                prev = (self._frame(prev_ref), prev_src)
             except Exception:  # predecessor frame gone from the store
                 prev = None
         # ONE compute, k queries — updated in place when the planner
@@ -267,16 +297,18 @@ class AnalyticsService:
                 self._cache[frame_ref] = out.source
                 self._cache.move_to_end(frame_ref)
                 self._evict_locked()
-        return out.source, out.results, False
+        return (out.source, out.results,
+                "updated" if incremental else "recomputed")
 
-    def _answer_group(self, frame_ref, group: list[_Pending]) -> list:
-        """Answer every request of one frame group; returns results in
-        group order."""
+    def _answer_group(self, frame_ref, group: list[_Pending]):
+        """Answer every request of one frame group; returns the results
+        in group order and the group's outcome (``_source_for``'s, or
+        ``"refetch"`` when a cache hit had to re-run the engine)."""
         from repro.core.engine import prefetch_rows
         from repro.core.hsource import BandedH, MissingRowsError
 
         queries = [p.query for p in group]
-        source, results, hit = self._source_for(frame_ref, queries)
+        source, results, outcome = self._source_for(frame_ref, queries)
         if results is None:
             # Cache hit: apply the queries to the cached source, sharing
             # one corner-row prefetch when the source streams (the same
@@ -292,8 +324,8 @@ class AnalyticsService:
                 # fall back on.  Re-run the engine (it re-plans with the
                 # new row union — fused again if still small) and refresh
                 # the cache.  Not a cache hit.
-                hit = False
-                out = self._engine.run(self._resolve(frame_ref), queries)
+                outcome = "refetch"
+                out = self._engine.run(self._frame(frame_ref), queries)
                 results = out.results
                 with self._lock:
                     self.stats.engine_runs += 1
@@ -304,10 +336,10 @@ class AnalyticsService:
                         self._evict_locked()
         with self._lock:
             self.stats.requests += len(group)
-            if hit:
+            if outcome == "hit":
                 self.stats.cache_hits += len(group)
             self.stats.coalesced += len(group) - 1
-        return results
+        return results, outcome
 
     def _on_device(self):
         """Pin this thread's dispatches to ``self.device`` (if any)."""
@@ -324,15 +356,18 @@ class AnalyticsService:
         results: list = [None] * len(batch)
         for frame_ref, members in groups.items():
             group = [p for _, p in members]
-            with self._on_device():
-                outs = self._answer_group(frame_ref, group)
-            done = time.perf_counter()
-            for (i, p), out in zip(members, outs):
-                results[i] = out
-                with self._lock:
-                    self.stats.observe(done - p.t_submit)
-                if p.future is not None:
-                    p.future.set_result(out)
+            with TraceAnnotation("service.group",
+                                 frame=str(frame_ref)) as span:
+                with self._on_device():
+                    outs, outcome = self._answer_group(frame_ref, group)
+                span.set_metadata(outcome=outcome)
+                done = time.perf_counter()
+                for (i, p), out in zip(members, outs):
+                    results[i] = out
+                    with self._lock:
+                        self.stats.observe(done - p.t_submit)
+                    if p.future is not None:
+                        p.future.set_result(out)
         return results
 
     # -- synchronous batch driver -------------------------------------------
@@ -348,7 +383,7 @@ class AnalyticsService:
         if self._worker is None:
             self._closing = False
             self._worker = threading.Thread(
-                target=self._drain_loop, name="analytics-service", daemon=True
+                target=self._drain_loop, name=self.name, daemon=True
             )
             self._worker.start()
         return self
@@ -371,14 +406,23 @@ class AnalyticsService:
             ) from None
         return p.future
 
-    def _drain_loop(self) -> None:
+    def _next_request(self) -> _Pending | None:
+        """Block until a request is queued; ``None`` once closing with
+        nothing left."""
         while True:
             try:
-                first = self._queue.get(timeout=0.05)
+                return self._queue.get(timeout=0.05)
             except queue.Empty:
                 if self._closing:
-                    return
-                continue
+                    return None
+
+    def _drain_loop(self) -> None:
+        device = -1 if self.device is None else self.device.id
+        while True:
+            with TraceAnnotation("service.wait"):
+                first = self._next_request()
+            if first is None:
+                return
             batch = [first]
             # greedy drain: whatever accumulated while the last batch
             # computed coalesces into this one
@@ -387,12 +431,16 @@ class AnalyticsService:
                     batch.append(self._queue.get_nowait())
                 except queue.Empty:
                     break
-            try:
-                self._process_batch(batch)
-            except Exception as e:  # fail the batch's futures, keep serving
-                for p in batch:
-                    if p.future is not None and not p.future.done():
-                        p.future.set_exception(e)
+            drained = time.perf_counter()
+            wait_us = 1e6 * sum(drained - p.t_submit for p in batch)
+            with TraceAnnotation("service.batch", size=len(batch),
+                                 wait_us=wait_us, device=device):
+                try:
+                    self._process_batch(batch)
+                except Exception as e:  # fail the batch, keep serving
+                    for p in batch:
+                        if p.future is not None and not p.future.done():
+                            p.future.set_exception(e)
 
     def close(self) -> None:
         """Drain outstanding requests, then stop the worker.

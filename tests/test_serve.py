@@ -293,6 +293,22 @@ def test_snapshot_counts_hits_beside_update_split(rng):
     assert snap["recomputed"] == 1 and snap["updated"] == 1
 
 
+def test_latency_samples_stay_bounded():
+    """A long-running service keeps the latencies of its most recent
+    requests only; the counters still count every request."""
+    from repro.serve.service import LATENCY_WINDOW, ServiceStats
+
+    stats = ServiceStats()
+    for i in range(LATENCY_WINDOW + 10):
+        stats.observe(1e-3 * i)
+    assert len(stats.latencies_s) == LATENCY_WINDOW
+    snap = stats.snapshot()
+    assert snap["completed"] == LATENCY_WINDOW + 10
+    # the 10 oldest samples are gone: the median is over 10 .. 4105 ms
+    assert snap["latency_p50_s"] == pytest.approx(
+        1e-3 * (10 + (LATENCY_WINDOW - 1) // 2))
+
+
 # ---------------------------------------------------------------------------
 # DistributedAnalyticsService (mesh-scale serving; 8-device runs live in
 # test_distributed.py's subprocess tests)
@@ -342,6 +358,15 @@ def test_distributed_routing_is_deterministic_across_instances(rng):
     assert [a.replica_for(r) for r in refs] == [b.replica_for(r) for r in refs]
     # and the ring spreads refs over more than one replica
     assert len({a.replica_for(r) for r in refs}) > 1
+
+
+def test_replica_workers_are_named_by_index(rng):
+    from repro.serve import DistributedAnalyticsService
+
+    with DistributedAnalyticsService(_dist_factory(), _video_store(rng),
+                                     num_replicas=3) as dist:
+        names = [r._worker.name for r in dist.replicas]
+    assert names == [f"analytics-service-{i}" for i in range(3)]
 
 
 def test_distributed_aggregate_backpressure(rng):
