@@ -1031,7 +1031,10 @@ class HistogramEngine:
         ``engine.run`` (``representation``, ``incremental``):
         ``engine.plan``, ``engine.validate``, ``engine.update`` or
         ``engine.compute`` (the H dispatch, with a band stream's row
-        prefetch), and one ``engine.query`` (``kind``) per query.
+        prefetch), and one ``engine.query`` (``kind``, ``path``) per
+        query: ``path`` is ``compiled`` where a dense H answered through
+        the compiled query programs, ``rows`` where the corner-row
+        protocol answered.
         """
         queries = list(queries)
         with TraceAnnotation("engine.run") as span:
@@ -1051,9 +1054,14 @@ class HistogramEngine:
                 target = source
                 if len(queries) > 1 and isinstance(source, BandedH):
                     target = prefetch_rows(source, queries) or source
+            # A dense H answers through the compiled query programs; the
+            # other representations through the corner-row protocol (a
+            # bin-sharded H's region queries through its shard_map).
+            path = "compiled" if isinstance(target, DenseH) else "rows"
             results = []
             for q in queries:
-                with TraceAnnotation("engine.query", kind=type(q).__name__):
+                with TraceAnnotation("engine.query", kind=type(q).__name__,
+                                     path=path):
                     results.append(q.apply(target))
         return EngineResult(plan=p, source=source, results=results)
 

@@ -188,10 +188,7 @@ class HSource(abc.ABC):
         *, stats: dict | None = None,
     ):
         hists = self.sliding_window_histograms(window, stride, stats=stats)
-        target_hist = jnp.asarray(target_hist)
-        if target_hist.ndim > 1:
-            target_hist = target_hist[..., None, None, :]
-        return metric(hists, target_hist)
+        return rq.score(hists, target_hist, metric)
 
     def multi_scale_search(
         self, target_hist, windows, metric, stride: int = 1
@@ -224,10 +221,7 @@ class HSource(abc.ABC):
                 hists = self._empty_windows(n_r, n_c)
             else:
                 hists = self._windows_from_rows(R, needed, wnd, stride)
-            t = jnp.asarray(target_hist)
-            if t.ndim > 1:
-                t = t[..., None, None, :]
-            maps.append(metric(hists, t))
+            maps.append(rq.score(hists, target_hist, metric))
         best_rect, best_score = rq.reduce_scale_maps(
             maps, windows, stride, self.lead
         )
@@ -255,9 +249,10 @@ class HSource(abc.ABC):
 class DenseH(HSource):
     """A materialized (..., b, h, w) H — thin adapter over ``jax.Array``.
 
-    Analytics delegate to the existing dense fast paths (direct advanced
-    indexing, strided-slice sliding windows); ``rows()`` exists for
-    protocol completeness and cross-representation tests."""
+    Analytics delegate to the compiled dense programs of
+    core/region_query.py (one program per query shape: regions, strided-
+    slice window histograms, the score); ``rows()`` exists for protocol
+    completeness and cross-representation tests."""
 
     def __init__(self, H):
         self.H = jnp.asarray(H)

@@ -87,3 +87,14 @@ def test_kernel_compiles_for_v5e(kernel, shape, one_chip, no_compile_cache):
     compiled = _lowered(kernel, n, h, w, bins, one_chip).compile()
     # the Pallas kernel is in the program, not a fallback
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_dense_window_program_compiles_for_v5e(one_chip, no_compile_cache):
+    """The 1080p 64x64 stride-2 window field compiles to strided slices:
+    no gather in the chip's program."""
+    from repro.core import region_query as rq
+
+    H = jax.ShapeDtypeStruct((32, 1080, 1920), jnp.float32,
+                             sharding=one_chip)
+    compiled = rq._dense_windows.lower(H, window=(64, 64), stride=2).compile()
+    assert "gather" not in compiled.as_text()
