@@ -2,12 +2,13 @@
 
 Every answer the timed window produced for a sampled frame is compared
 with the plain reference (``reference.py``) computed from the same frame
-and query.  The numbers compared, each held to the configuration's
+and query, at the corners of H the frame's queries read
+(``reference.corners``).  The numbers compared, each held to the configuration's
 limit (``limits`` in ``configs/<config>.json``):
 
 * ``count_mismatch``: region-histogram counts that differ from the exact
-  four-corner counts.  Counts are exact by the configuration (float32
-  holds every count of these frames), so the limit is 0.
+  four-corner counts.  Counts are exact by the configuration, so the
+  limit is 0.
 * ``lik_gap``: the widest gap between a served likelihood value and the
   float64 intersection of the exact window histograms.
 * ``ms_gap``: the same for multi-scale maps and best score, and the gap
@@ -23,6 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import ml_dtypes
 import numpy as np
 
 from chipbench import reference as ref
@@ -50,13 +52,47 @@ def _gap(got, want) -> float:
     return float(d.max()) if d.size else 0.0
 
 
+#: pixels up to which a float32 H counts every bin exactly (2**24).
+FLOAT32_EXACT = 1 << 24
+
+
+def control_dtype(cfg: dict):
+    """The precision of the configuration's control, the one below its
+    counts': where a frame has at most 2**24 pixels, float32 counts it
+    exactly and the control is bfloat16; past that, counts are exact only
+    in an integer H, and the control is float32."""
+    if cfg["height"] * cfg["width"] > FLOAT32_EXACT:
+        return np.float32
+    return ml_dtypes.bfloat16
+
+
+def grid(frame: np.ndarray, queries, cfg: dict, dtype) -> ref.Corners:
+    """The reference's P at every corner ``queries`` read, in ``dtype``."""
+    h, w = frame.shape
+    rows, cols = [], []
+    for q in queries:
+        kind = type(q).__name__
+        if kind == "RegionQuery":
+            r = np.asarray(q.rects)
+            rows += [r[:, 0], r[:, 2] + 1]
+            cols += [r[:, 1], r[:, 3] + 1]
+        elif kind in ("LikelihoodQuery", "MultiScaleQuery"):
+            wins = [q.window] if kind == "LikelihoodQuery" else q.windows
+            for wh, ww in wins:
+                rows.append(ref.window_lattice(h, wh, q.stride))
+                cols.append(ref.window_lattice(w, ww, q.stride))
+        else:
+            raise TypeError(f"no reference for {kind}")
+    return ref.corners(frame, cfg["bins"], cfg["value_range"],
+                       np.concatenate(rows), np.concatenate(cols), dtype)
+
+
 def compare(samples, cfg: dict, dtype=np.int32) -> dict:
     """Readings of every number compared over ``samples``; ``dtype`` is
     the precision the reference holds H in (the control lowers it)."""
-    bins, vr = cfg["bins"], cfg["value_range"]
     mismatch, lik, ms = 0, 0.0, 0.0
     for s in samples:
-        P = ref.padded(ref.integral_histogram(s.frame, bins, vr, dtype))
+        P = grid(s.frame, s.queries, cfg, dtype)
         for q, got in zip(s.queries, s.answers):
             kind = type(q).__name__
             if kind == "RegionQuery":
@@ -67,7 +103,7 @@ def compare(samples, cfg: dict, dtype=np.int32) -> dict:
             elif kind == "LikelihoodQuery":
                 want = ref.likelihood(P, q.target, q.window, q.stride)
                 lik = max(lik, _gap(got, want))
-            elif kind == "MultiScaleQuery":
+            else:
                 maps, best = ref.multiscale(P, q.target, q.windows, q.stride)
                 rect, score, got_maps = got
                 gaps = [_gap(g, w) for g, w in zip(got_maps, maps)]
@@ -76,8 +112,6 @@ def compare(samples, cfg: dict, dtype=np.int32) -> dict:
                 if len(got_maps) != len(maps):
                     gaps.append(float("inf"))
                 ms = max(ms, *gaps)
-            else:
-                raise TypeError(f"no reference for {kind}")
     return {"count_mismatch": mismatch, "lik_gap": lik, "ms_gap": ms,
             "checked": len(samples)}
 
@@ -85,8 +119,7 @@ def compare(samples, cfg: dict, dtype=np.int32) -> dict:
 def reference_answers(sample: Sample, cfg: dict, dtype) -> list:
     """The answers the reference gives with H held in ``dtype``, in the
     program's form (the control puts these in the program's place)."""
-    P = ref.padded(ref.integral_histogram(sample.frame, cfg["bins"],
-                                          cfg["value_range"], dtype))
+    P = grid(sample.frame, sample.queries, cfg, dtype)
     out = []
     for q in sample.queries:
         kind = type(q).__name__

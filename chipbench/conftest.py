@@ -9,13 +9,16 @@ from chipbench import cells
 
 #: Cells whose configuration and traffic files are here but which
 #: BENCHMARK.json does not list (PERF.md, Open questions): the fused-path
-#: search and the four-replica fleet.  Their tests keep the harness's
-#: multi-scale and replica paths working, so that a later PR adds either
-#: cell with an entry alone.
+#: search, the four-replica fleet, and the paper's 8192x8192x128 frame
+#: with its bins sharded over four chips.  Their tests keep the harness's
+#: multi-scale, replica and bin-sharded paths working, so that a later PR
+#: adds any of these cells with an entry alone.
 LATER = [{"name": "vga32.archive", "config": "vga32",
           "traffic": "archive_multiscale", "chips": 1},
          {"name": "vga32.fleet4", "config": "vga32", "traffic": "fleet",
-          "chips": 4}]
+          "chips": 4},
+         {"name": "paper8k128.sharded4", "config": "paper8k128",
+          "traffic": "archive_8k", "chips": 4}]
 
 
 def bench_with_later() -> dict:

@@ -8,10 +8,11 @@ seed with every number the check compared and the verdict ``correct`` of
 
 * with neither option: the program as it is (sound runs: the lower
   readings of each limit);
-* ``--control``: the reference with H held in bfloat16, the precision
-  below the configuration's float32 counts, put in the program's place
-  for the same frames and queries (the upper readings; the verdict has
-  to be false);
+* ``--control``: the reference with H held in the precision below the
+  configuration's counts (``check.control_dtype``: bfloat16, or
+  float32 for frames past 2**24 pixels), put in the
+  program's place for the same frames and queries (the upper readings;
+  the verdict has to be false);
 * ``--fault <name>``: the program with a fault of ``faults.py`` planted
   (the verdict has to be false).
 
@@ -42,7 +43,6 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import jax
-    import ml_dtypes
 
     from chipbench import cells, check, faults, runner
 
@@ -67,7 +67,8 @@ def main(argv=None) -> int:
             readings = {k: v["value"] for k, v in out["checks"].items()}
             correct = out["correct"]
             if args.control:
-                readings.update(check.control(kept, cfg, ml_dtypes.bfloat16))
+                readings.update(check.control(kept, cfg,
+                                              check.control_dtype(cfg)))
                 correct, _ = check.judge(readings, cfg["limits"])
             for k, v in readings.items():
                 worst[k] = max(worst.get(k, v), v)

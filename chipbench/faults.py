@@ -13,7 +13,10 @@ program leaves the faults planted where they were:
 * ``state_unchanged``: an incremental update hands back the cached
   predecessor's H unchanged, and the queries read that;
 * ``chain_ignored``: the router places each frame by its index alone, so
-  a camera's chain is split over replicas.
+  a camera's chain is split over replicas;
+* ``shard_lost``: the exchange between chips left out: an H whose bins
+  are sharded over chips answers with the last chip's share of the bins
+  zeroed, as if that chip's part never reached the queries.
 """
 
 from __future__ import annotations
@@ -90,8 +93,33 @@ def chain_ignored():
         yield
 
 
+@contextlib.contextmanager
+def shard_lost():
+    from repro.core import engine
+    from repro.core.hsource import ShardedH
+
+    real = engine.HistogramEngine.run
+
+    def run(self, frames, queries=(), *, prev=None):
+        queries = list(queries)
+        out = real(self, frames, queries, prev=prev)
+        src = out.source
+        if not isinstance(src, ShardedH) or src.kind != "bin":
+            return out
+        kept = src.num_bins - src.num_bins // src.mesh.shape[src.bin_axis]
+        lost = ShardedH(src.H.at[..., kept:, :, :].set(0), src.mesh,
+                        kind="bin", bin_axis=src.bin_axis,
+                        row_axis=src.row_axis)
+        return engine.EngineResult(plan=out.plan, source=lost,
+                                   results=[q.apply(lost) for q in queries])
+
+    with _patched(engine.HistogramEngine, "run", run):
+        yield
+
+
 FAULTS = {
     "answer_altered": answer_altered,
     "state_unchanged": state_unchanged,
     "chain_ignored": chain_ignored,
+    "shard_lost": shard_lost,
 }

@@ -159,7 +159,14 @@ def _host(answer):
 
 def build_service(cfg: dict, mix: dict, store: FrameStore, rec: Recorder,
                   devices):
-    """The service a cell drives, every engine instrumented."""
+    """The service a cell drives, every engine instrumented.
+
+    The cell's devices are laid out as a ``(replicas, shards)`` mesh on
+    the axes ``("data", "model")``, with the mix's ``replicas`` (1 where
+    not given) and ``shards`` the devices left to each: frames are routed
+    over replicas, and each replica's engine shards H's bins over its
+    ``shards`` devices.  With no devices (the CPU tests) every replica is
+    one engine on the default device, unsharded."""
     from repro.core.engine import HistogramEngine
     from repro.serve import (AnalyticsService, DistributedAnalyticsService,
                              sharded_engine_factory)
@@ -177,20 +184,24 @@ def build_service(cfg: dict, mix: dict, store: FrameStore, rec: Recorder,
     else:
         pending = mix["clients"] * queries
     svc_kw["max_pending"] = max(64, int(math.ceil(pending)))
-    if replicas == 1:
+    shards = 1 if devices is None else len(devices) // replicas
+    if replicas == 1 and shards == 1:
         engine = rec.instrument(HistogramEngine(
             cfg["bins"], value_range=cfg["value_range"], **cfg["engine"]))
         return AnalyticsService(engine, store, predecessor=predecessor,
                                 **svc_kw)
+    engine_kw = dict(cfg["engine"])
+    if shards > 1:
+        engine_kw["sharding"] = "bin"
     factory = sharded_engine_factory(cfg["bins"],
                                      value_range=cfg["value_range"],
-                                     **cfg["engine"])
+                                     **engine_kw)
     kw = dict(svc_kw, cache_bytes=svc_kw["cache_bytes"] * replicas)
     if devices is not None:
         from repro.compat import make_mesh
 
-        mesh = make_mesh((replicas, 1), ("data", "model"),
-                         devices=devices[:replicas])
+        mesh = make_mesh((replicas, shards), ("data", "model"),
+                         devices=devices[:replicas * shards])
         kw.update(mesh=mesh, replica_axis="data")
     else:                       # one device: the degenerate layout
         kw.update(num_replicas=replicas)

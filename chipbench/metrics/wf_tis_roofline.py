@@ -1,7 +1,12 @@
 """``wf_tis``'s share of its HBM roofline in the traced stretch: the bytes
 its work needs (``work.wf_tis_bytes`` of the frames it computed, at the
-configuration's frame shape) at peak bandwidth, over the summed device
-time of its kernel events, in percent."""
+configuration's frame shape and bins) at peak bandwidth, over the summed
+device time of its kernel events on every chip, in percent.
+
+A frame whose bins are sharded over chips gives one event per chip, each
+over its share of the bins (the second of the output's four dims): the
+events of a frame add up to one frame, so a frame's work is counted
+once, and the share is of the chips' combined roofline."""
 
 from chipbench import peaks, work
 
@@ -12,7 +17,8 @@ def read(run):
     if not events:
         return None
     cfg = run.cfg
-    frames = sum(dims[0] if len(dims) == 4 else 1 for _, dims in events)
+    frames = sum(dims[0] * dims[1] / cfg["bins"] if len(dims) == 4 else 1
+                 for _, dims in events)
     nbytes = work.wf_tis_bytes(frames, cfg["height"], cfg["width"],
                                cfg["bins"])
     bw = peaks.peaks_for(run.device_kind)["hbm_bytes_per_s"]

@@ -131,7 +131,7 @@ def execute(cell: dict, cfg: dict, mix: dict, *, seed: int, seconds: float,
         f"{k} x{n}" for k, n in sorted(window_plans.items())))
     log("counters in window: " + ", ".join(
         f"{k}={run.end['total'][k] - run.start['total'][k]}"
-        for k in run.end["total"]))
+        for k in run.end["total"]) + f"; compiles={run.compiles_in_window}")
 
     e2e, per_layer = cells.metrics_for(cell["name"], bench)
     metrics = {}

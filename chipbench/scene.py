@@ -18,6 +18,10 @@ Cameras:
   crop of a larger background at a new offset with objects pasted in, so
   every frame is new and has no predecessor.
 
+A mix's ``flat`` (``{"share": s}``) lays one more flat area over each
+background: rows of one value across the whole width, at least ``s`` of
+every frame's rows wherever the pan crops it.
+
 Queries per frame come from the mix's ``queries`` list: ``likelihood``
 (whole-frame sliding-window likelihood against a target histogram),
 ``fragments`` (a ``RegionQuery`` of a 3x3 grid of tracker fragments per
@@ -29,6 +33,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import numpy as np
 
@@ -173,7 +178,14 @@ class Stream:
         if key != self._bg_key:
             h, w = ((self.h, self.w) if self.fixed
                     else (self.canvas_h, self.canvas_w))
-            self._bg = background(rng_for(self.seed, self.client, 3, key), h, w)
+            rng = rng_for(self.seed, self.client, 3, key)
+            self._bg = background(rng, h, w)
+            if "flat" in self.mix:
+                # the rows a crop can start below the canvas's top, and
+                # then the share of a frame's rows
+                rows = h - self.h + math.ceil(self.mix["flat"]["share"]
+                                              * self.h)
+                self._bg[:rows] = rng.integers(0, 256)
             self._bg_key = key
         return self._bg
 

@@ -41,6 +41,7 @@ class _nothing:
     ("vga32.fleet4", "chain_ignored"),
     ("vga32.fleet4", "answer_altered"),
     ("vga32.fleet4", "state_unchanged"),
+    ("paper8k128.sharded4", "answer_altered"),
 ])
 def test_fault_fails_the_check(small_cell, name, fault):
     out = run_cell(small_cell, name, fault)
@@ -49,7 +50,8 @@ def test_fault_fails_the_check(small_cell, name, fault):
 
 
 @pytest.mark.parametrize("name", ["vga32.live", "hd32.archive",
-                                  "vga32.archive", "vga32.fleet4"])
+                                  "vga32.archive", "vga32.fleet4",
+                                  "paper8k128.sharded4"])
 def test_sound_run_passes_and_bf16_control_fails(small_cell, name):
     kept = []
     out = run_cell(small_cell, name, samples=kept)

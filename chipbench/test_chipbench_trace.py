@@ -86,3 +86,43 @@ def test_wf_tis_roofline_of_the_recorded_1080p_frame():
     assert share == pytest.approx(100 * 267_494_400 / 819e9 / 862.972e-6,
                                   rel=1e-6)
     assert 37.8 < share < 37.9
+
+
+def roofline(kernels, cfg):
+    s = trace.Summary(window_s=1.0, busy_s=1.0, devices=1, device_ops=[],
+                      idle_gaps=[], kernels=kernels)
+    run = types.SimpleNamespace(trace=s, cfg=cfg, device_kind="TPU v5 lite")
+    return cells.reader("wf_tis_roofline.fps")(run)
+
+
+def test_wf_tis_roofline_counts_a_bin_sharded_frame_once():
+    """Four chips' events, each over a quarter of the bins, read the same
+    share as one chip's single event over the whole frame in the same
+    summed time: the frame's work is counted once, over the chips'
+    combined time."""
+    cfg = cells.config("paper8k128")
+    h, w, b = cfg["height"], cfg["width"], cfg["bins"]
+    whole = roofline({"wf_tis": [(0.2, (1, b, h, w))] * 3}, cfg)
+    quarters = roofline({"wf_tis": [(0.05, (1, b // 4, h, w))] * 12}, cfg)
+    assert quarters == pytest.approx(whole, rel=1e-12)
+    assert whole == pytest.approx(
+        100 * 3 * (h * w + 4 * b * h * w) / 819e9 / 0.6, rel=1e-12)
+
+
+def test_sharded_kernel_is_found_by_its_op_name():
+    """In the program that shards bins over chips the kernel sits in no
+    program of ``KERNEL_PROGRAMS``: its op's own name finds it, on every
+    chip, and other custom calls stay out."""
+    op = ("%wf_tis.1 = f32[1,32,512,1024]{3,2,1,0:T(8,128)} custom-call("
+          "%subtract_select_fusion, %broadcast_in_dim.6)")
+    other = "%cw_tis_hscan.3 = f32[1,32,512,1024]{3,2,1,0} custom-call(%a)"
+    dev = {"XLA Modules": [(1.0, 2.0, "jit_shard_fn(7)")],
+           "XLA Ops": [(1.0, 1.25, op), (1.25, 1.5, other)]}
+    s = trace.summarize([dev] * 4, [(0.0, 3.0, "bench.trace")])
+    assert s.kernels == {"wf_tis": [(0.25, (1, 32, 512, 1024))] * 4}
+    assert trace.kernel_of("jit_shard_fn", "ROOT " + op) == "wf_tis"
+    assert trace.kernel_of("jit__integral_histogram_jit", WF) == "wf_tis"
+    assert trace.kernel_of("jit_shard_fn", other) is None
+    cfg = dict(cells.config("paper8k128"), height=512, width=1024)
+    assert roofline(s.kernels, cfg) == pytest.approx(
+        100 * (512 * 1024 + 4 * 128 * 512 * 1024) / 819e9 / 1.0, rel=1e-12)

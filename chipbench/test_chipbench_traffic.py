@@ -58,6 +58,20 @@ def test_moving_camera_frames_are_all_new():
     assert np.mean(np.any(f0 != f1, axis=1)) > 0.35
 
 
+def test_aerial_frames_have_one_bin_past_2_24_pixels():
+    """Every full-size frame of the 8192x8192 aerial mix, wherever the pan
+    crops it, has a flat area of one bin over 30% of its pixels: past
+    2**24, where float32 counts only even integers."""
+    _, cfg, mix = cell("paper8k128.sharded4")
+    s = scene.streams(cfg, mix, 2**31 + 17)[1]
+    for t in (0, 37):
+        f = s.frame(t)
+        assert f.shape == (8192, 8192)
+        counts = np.bincount(scene.bin_ids(f, cfg["bins"],
+                                           cfg["value_range"]).ravel())
+        assert counts.max() >= 0.3 * 2**26 - 32 * 48 * 48 > 2**24
+
+
 def test_sampling_is_drawn_from_the_seed():
     picks = [scene.sampled(5, n, 4) for n in range(400)]
     assert picks == [scene.sampled(5, n, 4) for n in range(400)]

@@ -9,9 +9,11 @@ A traced run records one stretch of its window with ``jax.profiler``
   Their union is the device's busy time.
 * ``XLA Ops``: one event per HLO op, named by its HLO text
   (``%name = f32[1,32,1152,1920]{...} custom-call(...)``).  A Pallas
-  kernel is the ``custom-call`` op inside the program that wraps it; the
-  table ``KERNEL_PROGRAMS`` names those programs, since the kernels carry
-  no ``name=`` of their own.
+  kernel is the ``custom-call`` op inside the program that wraps it (the
+  table ``KERNEL_PROGRAMS`` names those programs, for traces whose
+  kernels carry no ``name=`` of their own), or a ``custom-call`` op
+  named after the kernel (``%wf_tis.1 = ...``) in any program, as in the
+  program that shards H's bins over chips.
 
 Host spans (``TraceAnnotation``) are on the ``/host:CPU`` plane, on the
 same clock, so every idle gap of a device is labelled by the benchmark
@@ -39,6 +41,7 @@ GAP_LABELS = ("validate", "frame.resolve", "query.apply", "engine.run",
 
 _SHAPE = re.compile(r"^\S+ = [a-z0-9]+\[([0-9,]*)\]")
 _OPCODE = re.compile(r"[\]})] ([a-z][a-z0-9-]*)\(")
+_OP_NAME = re.compile(r"^(?:ROOT )?%([A-Za-z_][A-Za-z0-9_]*?)(?:\.[0-9]+)? = ")
 
 
 @dataclasses.dataclass
@@ -62,6 +65,16 @@ def opcode(op_name: str) -> str:
     """The HLO opcode of an ``XLA Ops`` event (``custom-call``, ...)."""
     m = _OPCODE.search(op_name, op_name.find(" = ") + 1)
     return m.group(1) if m else op_name.split(" ", 1)[0]
+
+
+def kernel_of(program: str, op_name: str) -> str | None:
+    """The kernel a ``custom-call`` op is: by the program around it, or
+    by the op's own name; ``None`` for another op."""
+    for kernel, p in KERNEL_PROGRAMS.items():
+        if program == p:
+            return kernel
+    m = _OP_NAME.match(op_name)
+    return m.group(1) if m and m.group(1) in KERNEL_PROGRAMS else None
 
 
 def out_dims(op_name: str) -> tuple:
@@ -151,10 +164,9 @@ def summarize(devices, spans, top: int = 10) -> Summary | None:
                     if starts and starts[k][0] <= a <= starts[k][1] else "?")
             code = opcode(name)
             ops[f"{prog}:{code}"] += min(b, w1) - max(a, w0)
-            if code == "custom-call":
-                for kernel, p in KERNEL_PROGRAMS.items():
-                    if prog == p:
-                        kernels[kernel].append((b - a, out_dims(name)))
+            kernel = kernel_of(prog, name) if code == "custom-call" else None
+            if kernel is not None:
+                kernels[kernel].append((b - a, out_dims(name)))
     n = len(devices)
     gaps.sort(reverse=True)
     return Summary(

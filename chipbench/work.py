@@ -11,7 +11,7 @@ H_ITEM = 4        # float32 counts
 PIXEL = 1         # uint8 frames
 
 
-def wf_tis_bytes(frames: int, h: int, w: int, bins: int) -> int:
+def wf_tis_bytes(frames: float, h: int, w: int, bins: int) -> float:
     """Read ``frames`` (h, w) uint8 frames, write their (bins, h, w) H."""
     return frames * (h * w * PIXEL + bins * h * w * H_ITEM)
 
