@@ -415,7 +415,8 @@ def plan_geometry(plan) -> KernelGeometry:
     if plan.band_plan is not None:
         h = plan.band_plan.band_h
     return KernelGeometry(n=n, h=h, w=s.width, num_bins=s.num_bins,
-                          tile=plan.tile, bin_block=plan.bin_block)
+                          tile=plan.tile, bin_block=plan.bin_block,
+                          dtype=s.h_dtype.name)
 
 
 def vmem_required(method: str, geom: KernelGeometry

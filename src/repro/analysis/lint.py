@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import ast
 import dataclasses
+import functools
 import hashlib
 import io
 import json
@@ -416,12 +417,31 @@ def const_int(node: ast.AST, env: dict[str, int]) -> int | None:
 
 
 def module_int_env(tree: ast.AST) -> dict[str, int]:
-    """Module-level ``NAME = <int expr>`` bindings, const-folded in order."""
+    """Module-level ``NAME = <int expr>`` bindings, const-folded in order,
+    and the int constants a ``from repro.<module> import NAME`` brings
+    in from another module of this package."""
     env: dict[str, int] = {}
     for stmt in getattr(tree, "body", []):
-        if isinstance(stmt, ast.Assign) and len(stmt.targets) == 1 \
+        if isinstance(stmt, ast.ImportFrom) and stmt.level == 0 \
+                and (stmt.module or "").startswith("repro."):
+            imported = _package_int_env(stmt.module)
+            for alias in stmt.names:
+                if alias.name in imported:
+                    env[alias.asname or alias.name] = imported[alias.name]
+        elif isinstance(stmt, ast.Assign) and len(stmt.targets) == 1 \
                 and isinstance(stmt.targets[0], ast.Name):
             v = const_int(stmt.value, env)
             if v is not None:
                 env[stmt.targets[0].id] = v
     return env
+
+
+@functools.lru_cache(maxsize=None)
+def _package_int_env(module: str) -> dict[str, int]:
+    """``module_int_env`` of a module of this package, read from its
+    source next to this file's package (empty when there is none)."""
+    path = Path(__file__).resolve().parents[1].joinpath(
+        *module.split(".")[1:]).with_suffix(".py")
+    if not path.is_file():
+        return {}
+    return module_int_env(ast.parse(path.read_text()))

@@ -11,7 +11,8 @@ FLOPs, no device memory) plus the planner's own metadata.
   * **representation** — the plan's decision is internally consistent
     (known representation, mesh-axis divisibility for sharded plans);
   * **h-shape** — the kernel the plan selects produces the (..., b, h, w)
-    fp32 H the representation expects, via ``jax.eval_shape``;
+    H the representation expects, in the frame's element type
+    (``core/hdtype.h_dtype``), via ``jax.eval_shape``;
   * **carry-chain** — every band height in the band plan accepts and
     re-emits the (..., b, w) bottom-row carry (again by eval_shape);
   * **memory-budget** — the peak *live* H footprint (microbatch x
@@ -20,11 +21,13 @@ FLOPs, no device memory) plus the planner's own metadata.
   * **vmem-fit** — Pallas plans: the per-core VMEM working set
     (double-buffered in/out blocks + carry + scratch) fits the ~16 MiB
     budget, from the kernels' block specs;
-  * **count-validity** — the §4.6 exactness regime: storage-policy
-    plans hard-fail when the frame's pixel count exceeds the fp32
-    exact-integer range (mirroring ``validate_storage_policy``);
-    plain fp32 plans get a warning, since per-query bounds are
-    enforced at query time;
+  * **count-validity** — the §4.6 exactness regime: a frame of 2**24
+    pixels or more needs an int32 H, which only ``wf_tis`` on the dense,
+    banded or sharded representations accumulates; a plan that would
+    hold such a frame in fp32 (a storage policy, the fused or
+    incremental paths, another method) fails, and an int32 plan is
+    held to the bound that keeps its in-tile fp32 scans exact
+    (tile x padded width < 2**24);
   * **query-validity** — when queries are supplied: each query's
     largest region/window area fits the plan's exact-count bound
     (``uint16``: 65535 px of modular arithmetic);
@@ -46,7 +49,8 @@ import functools
 import jax
 import numpy as np
 
-from repro.core.bands import FP32_EXACT_COUNT, STORAGE_POLICIES
+from repro.core.bands import STORAGE_POLICIES
+from repro.core.hdtype import FP32_EXACT_COUNT, exact_count_bound
 
 #: per-core VMEM budget the Pallas kernels must fit (bytes).
 VMEM_LIMIT_BYTES = 16 << 20
@@ -113,7 +117,7 @@ def _eval_kernel(plan, h: int, w: int, *, with_carry: bool):
     lead = _lead(plan)
     img = jax.ShapeDtypeStruct((*lead, h, w), np.dtype(s.dtype))
     carry = (
-        jax.ShapeDtypeStruct((*lead, s.num_bins, w), np.float32)
+        jax.ShapeDtypeStruct((*lead, s.num_bins, w), s.h_dtype)
         if with_carry else None
     )
 
@@ -122,7 +126,7 @@ def _eval_kernel(plan, h: int, w: int, *, with_carry: bool):
             image, s.num_bins, method=plan.method, backend=plan.backend,
             tile=plan.tile, bin_block=plan.bin_block, use_mxu=s.use_mxu,
             interpret=s.interpret, value_range=s.value_range,
-            carry_in=carry_in,
+            carry_in=carry_in, dtype=s.h_dtype,
         )
 
     return jax.eval_shape(fn, img, carry)
@@ -225,12 +229,12 @@ def _check_h_shape(plan) -> PlanCheck:
         return PlanCheck(
             name, "fail",
             f"kernel yields {tuple(out.shape)}, plan expects {expect}")
-    if out.dtype != np.float32:
+    if out.dtype != s.h_dtype:
         return PlanCheck(
             name, "fail",
-            f"kernel yields {out.dtype}, engine arithmetic is fp32")
+            f"kernel yields {out.dtype}, the frame's H is {s.h_dtype}")
     return PlanCheck(
-        name, "ok", f"{expect} float32 via {plan.method}/{plan.backend}")
+        name, "ok", f"{expect} {out.dtype} via {plan.method}/{plan.backend}")
 
 
 def _check_carry_chain(plan) -> PlanCheck:
@@ -328,33 +332,53 @@ def _plan_exact_bound(plan) -> int:
     """Largest region pixel count queries on this plan read back exactly."""
     if plan.storage is not None:
         return int(STORAGE_POLICIES[plan.storage][1])
-    return FP32_EXACT_COUNT - 1
+    return exact_count_bound(plan.spec.h_dtype)
+
+
+def _fp32_path(plan) -> str | None:
+    """What of the plan holds H in fp32 whatever the frame's size, or
+    ``None`` when the plan follows the frame's element type."""
+    if plan.storage is not None:
+        return f"the {plan.storage} spill (bands and carries held in fp32)"
+    if plan.representation == "fused":
+        return "the fused corner-row kernel (fused_rows accumulates fp32)"
+    if plan.incremental:
+        return "the incremental update (delta_apply repairs H in fp32)"
+    if plan.method != "wf_tis":
+        return f"the {plan.method} scan (only wf_tis accumulates int32)"
+    return None
 
 
 def _check_count_validity(plan) -> PlanCheck:
     name = "count-validity"
     s = plan.spec
     px = s.height * s.width
-    if plan.storage is not None:
-        bound = _plan_exact_bound(plan)
-        if px >= FP32_EXACT_COUNT:
+    if px < FP32_EXACT_COUNT:
+        if plan.storage is not None:
             return PlanCheck(
-                name, "fail",
-                f"{s.height}x{s.width} frame accumulates up to {px} "
-                f"counts, beyond fp32 exact range {FP32_EXACT_COUNT} — "
-                f"no storage policy recovers exactness; shard spatially")
+                name, "ok",
+                f"{plan.storage} spill: regions <= "
+                f"{_plan_exact_bound(plan)} px exact (modular arithmetic)")
         return PlanCheck(
-            name, "ok",
-            f"{plan.storage} spill: regions <= {bound} px exact "
-            f"(modular arithmetic)")
-    if px >= FP32_EXACT_COUNT:
+            name, "ok", f"{px}-px frame within fp32 exact range")
+    fp32 = _fp32_path(plan)
+    if fp32 is not None:
         return PlanCheck(
-            name, "warn",
-            f"{px}-px frame exceeds the fp32 exact range "
-            f"{FP32_EXACT_COUNT}; only regions <= "
-            f"{FP32_EXACT_COUNT - 1} px are exact (enforced per query)")
+            name, "fail",
+            f"{s.height}x{s.width} frame accumulates up to {px} counts, "
+            f"beyond the fp32 exact range {FP32_EXACT_COUNT}, and {fp32} "
+            "would round them — plan the dense, banded or sharded wf_tis "
+            "path, whose H is int32 at this size")
+    strip = plan.tile * (-(-s.width // plan.tile) * plan.tile)
+    if strip >= FP32_EXACT_COUNT:
+        return PlanCheck(
+            name, "fail",
+            f"the in-tile fp32 scans reach tile x padded width = {strip}, "
+            "not below 2**24")
     return PlanCheck(
-        name, "ok", f"{px}-px frame within fp32 exact range")
+        name, "ok",
+        f"{px}-px frame in an int32 H; in-tile fp32 scans <= {strip} "
+        "< 2**24")
 
 
 def _check_incremental(plan) -> PlanCheck:
@@ -463,7 +487,7 @@ def _check_queries(plan, queries) -> PlanCheck:
                 f"{type(q).__name__} touches a {area}-px region, beyond "
                 f"the plan's exact-count bound {bound} px"
                 + (f" ({plan.storage} modular arithmetic wraps)"
-                   if plan.storage else " (fp32 exactness)"))
+                   if plan.storage else f" ({plan.spec.h_dtype} H)"))
         worst = max(worst, area)
     detail = f"largest region {worst} px <= {bound} px bound"
     if opaque:

@@ -15,16 +15,18 @@ for a band starting at row r0,
     H[r, c, b] = H_band[r - r0, c, b] + H[r0 - 1, c, b]
 
 The whole cross-band dependency is one (..., b, w) bottom-row carry.  All
-arithmetic is integer-valued fp32 (exact below 2**24 counts), so banded
-results are bit-exact vs the monolithic computation — asserted, not
-approximated, in tests/test_bands.py.
+arithmetic is integer-valued in H's element type (core/hdtype.py: fp32
+below 2**24 pixels, int32 past it — decided by the frame, not the band),
+so banded results are bit-exact vs the monolithic computation — asserted,
+not approximated, in tests/test_bands.py.
 
 Three consumption modes, none of which materializes the (b, h, w) H:
 
   * stream — ``iter_banded_ih`` yields ``BandH`` chunks to a consumer
     (the banded O(1) queries in core/region_query.py consume these);
   * spill  — ``spill_banded_ih`` stores bands host-side under a storage
-    policy.  ``float32`` keeps counts exact below 2**24; the reduced-width
+    policy, for frames below 2**24 pixels.  ``float32`` keeps every count
+    exact there; the reduced-width
     integer policies wrap modularly (``uint16`` halves the footprint and
     any four-corner query over a region of <= 65535 pixels stays exact
     despite the wraparound — the embedded-systems accumulator trick of
@@ -45,12 +47,9 @@ from typing import Callable, Iterable, Iterator
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.hdtype import FP32_EXACT_COUNT, h_dtype
 from repro.core.hsource import HSource
 from repro.kernels.ops import integral_histogram
-
-# fp32 represents consecutive integers exactly only below 2**24; beyond it
-# the accumulated counts themselves (not just a storage cast) are wrong.
-FP32_EXACT_COUNT = 1 << 24
 
 # Storage policies for spilled bands: numpy dtype + the largest region
 # pixel count a four-corner query is guaranteed exact for.  Integer
@@ -67,11 +66,12 @@ STORAGE_POLICIES = {
 def validate_storage_policy(storage: str, h: int, w: int) -> None:
     """Validate a spill policy against the count bound of an (h, w) frame.
 
-    The kernels accumulate in fp32, so any frame whose total pixel count
-    reaches 2**24 has inexact counts before storage even starts — no
-    policy can recover that; shard spatially (core/distributed.py)
-    instead.  ``uint16``'s additional <= 65535-pixel *region* bound is
-    enforced at query time (``SpilledIH.region_histogram``).
+    A spill holds its bands and their carries in fp32 before the storage
+    cast, so a frame of 2**24 pixels or more would round before storage
+    even starts — no policy recovers that.  Such a frame keeps an int32 H
+    on the device instead (the dense, banded or bin-sharded path; see
+    core/hdtype.py).  ``uint16``'s additional <= 65535-pixel *region*
+    bound is enforced at query time (``SpilledIH.region_histogram``).
     """
     if storage not in STORAGE_POLICIES:
         raise ValueError(
@@ -81,8 +81,9 @@ def validate_storage_policy(storage: str, h: int, w: int) -> None:
     if h * w >= FP32_EXACT_COUNT:
         raise ValueError(
             f"{h}x{w} frame accumulates counts up to {h * w}, beyond the "
-            f"fp32 exact-integer range 2**24; no storage policy recovers "
-            "exactness — use spatial sharding (core/distributed.py)"
+            f"fp32 exact-integer range 2**24; a spill holds fp32 and no "
+            "storage policy recovers exactness — keep the H on the device "
+            "(dense, banded or bin-sharded: an int32 H at this size)"
         )
 
 
@@ -187,6 +188,7 @@ def iter_banded_ih(
     use_mxu: bool = True,
     interpret: bool = False,
     value_range: int = 256,
+    dtype=None,
 ) -> Iterator[BandH]:
     """Stream the integral histogram of ``image`` as row bands.
 
@@ -200,6 +202,8 @@ def iter_banded_ih(
     spatially-sharded with the same carry chain.  ``prefetch >= 1`` keeps
     that many band image slices staged on device ahead of the one
     computing (the §4.4 overlap applied inside one large frame).
+    ``dtype`` is H's element type, ``h_dtype`` of the whole frame unless
+    given: every band, and the carry between them, holds it.
     ``device`` is any staging placement (``Device`` or ``Sharding``);
     when given, slices are staged even at ``prefetch=0`` — a
     ``NamedSharding`` commits each slice to the layout a sharded
@@ -220,12 +224,14 @@ def iter_banded_ih(
             num_frames=num_frames,
         )
     if compute_fn is None:
+        dtype = h_dtype(h, w) if dtype is None else dtype
+
         def compute_fn(band_img, carry):
             return integral_histogram(
                 band_img, num_bins, method=method, backend=backend,
                 tile=tile, bin_block=bin_block, use_mxu=use_mxu,
                 interpret=interpret, value_range=value_range,
-                carry_in=carry,
+                carry_in=carry, dtype=dtype,
             )
 
     def step(band_img, carry):

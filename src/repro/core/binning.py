@@ -46,9 +46,9 @@ def one_hot_bins(idx: jnp.ndarray, num_bins: int, dtype=jnp.float32) -> jnp.ndar
     frame maps (h, w) -> (b, h, w) and a frame stack maps
     (n, h, w) -> (n, b, h, w).
 
-    fp32 is exact for counts < 2**24 — the largest supported image plane
-    (8k x 8k = 2**26) is handled by the fp64-accumulation flag in ref.py or
-    by int32 accumulation; for every benchmarked shape fp32 is exact.
+    The 0/1 mask itself is exact in any type; what the scans accumulate
+    from it takes H's element type (core/hdtype.py: fp32 below 2**24
+    pixels, int32 past it).
     """
     b = jnp.arange(num_bins, dtype=jnp.int32)
     return (idx[..., None, :, :] == b[:, None, None]).astype(dtype)

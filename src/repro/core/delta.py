@@ -24,9 +24,11 @@ through the band's bottom row.  The incremental walk over a band plan:
     bottom row is ``old_bottom + delta``, so consecutive clean bands
     reuse the same delta without any rescan.
 
-All H arithmetic is integer-valued fp32 (exact below 2**24, validated
-upstream), so the updated H is **bit-exact** against a monolithic
-recompute — asserted, not approximated, in tests/test_delta.py.  The
+All H arithmetic is integer-valued fp32, which the frame's element type
+(core/hdtype.py) allows only below 2**24 pixels: the planner takes this
+path only there, and plancheck refuses it past that.  So the updated H
+is **bit-exact** against a monolithic recompute — asserted, not
+approximated, in tests/test_delta.py.  The
 integer spill policies update in the same modular arithmetic they
 store in; their true-valued fp32 carry chain is retained on the
 ``SpilledIH`` (``carries``) precisely so the delta can be formed

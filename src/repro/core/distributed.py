@@ -35,12 +35,16 @@ The exclusive cross-device prefix is implemented two ways:
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.binning import PAD_BIN, bin_indices
+from repro.core.hdtype import h_dtype
 from repro.core.scans import apply_carry
 from repro.kernels.ops import integral_histogram
 
@@ -136,16 +140,32 @@ def bin_sharded_ih(
     method: str = "wf_tis",
     backend: str = "jnp",
     value_range: int = 256,
+    dtype=None,
 ) -> jnp.ndarray:
     """Paper's multi-GPU scheme: bins sharded over ``bin_axis``.
 
     Accepts an (h, w) frame or an (n, h, w) stack (one batched dispatch
-    per shard).  Returns H ([n,] num_bins, h, w) sharded over bins.
+    per shard).  Returns H ([n,] num_bins, h, w) sharded over bins, of
+    ``dtype`` (``h_dtype`` of the image unless given: a band of a larger
+    frame names the frame's).  The program is compiled once per mesh and
+    geometry (``_bin_sharded_program``).
     """
     nshards = mesh.shape[bin_axis]
     if num_bins % nshards:
         raise ValueError(f"{num_bins} bins not divisible by {nshards} shards")
-    local_bins = num_bins // nshards
+    dtype = h_dtype(*image.shape[-2:]) if dtype is None else np.dtype(dtype)
+    fn = _bin_sharded_program(mesh, bin_axis, num_bins, image.ndim - 2,
+                              method, backend, value_range, dtype)
+    return fn(image)
+
+
+@functools.lru_cache(maxsize=32)
+def _bin_sharded_program(mesh, bin_axis, num_bins, lead, method, backend,
+                         value_range, dtype):
+    """``bin_sharded_ih``'s jitted shard_map, kept per (mesh, geometry):
+    built per call, the shard_map would be traced and lowered again for
+    every frame."""
+    local_bins = num_bins // mesh.shape[bin_axis]
 
     def shard_fn(img):
         idx = bin_indices(img, num_bins, value_range)
@@ -155,18 +175,16 @@ def bin_sharded_ih(
         )
         return integral_histogram(
             local_idx, local_bins, method=method, backend=backend,
-            value_range=None,
+            value_range=None, dtype=dtype,
         )
 
-    lead = image.ndim - 2                   # 0 single frame, 1 frame stack
-    fn = shard_map(
+    return jax.jit(shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=P(),                       # frame(s) replicated
         out_specs=P(*([None] * lead), bin_axis, None, None),
         check_vma=False,
-    )
-    return fn(image)
+    ))
 
 
 def spatial_sharded_ih(
@@ -180,6 +198,7 @@ def spatial_sharded_ih(
     backend: str = "jnp",
     value_range: int = 256,
     scan_impl: str = "allgather",
+    dtype=None,
 ) -> jnp.ndarray:
     """Beyond-paper: row strips over ``row_axis`` (+ optional bin sharding).
 
@@ -187,10 +206,13 @@ def spatial_sharded_ih(
     bottom-boundary carries sweep down the mesh axis as an exclusive
     prefix — the WF-TiS column carry at ICI scale.
 
-    Returns H (num_bins, h, w) sharded P(bin_axis, row_axis, None).
+    Returns H (num_bins, h, w) sharded P(bin_axis, row_axis, None), of
+    ``dtype`` (``h_dtype`` of the whole image unless given; every strip
+    holds it).
     """
     d_rows = mesh.shape[row_axis]
     h = image.shape[0]
+    dtype = h_dtype(*image.shape[-2:]) if dtype is None else np.dtype(dtype)
     if h % d_rows:
         raise ValueError(f"height {h} not divisible by {d_rows} row shards")
     local_bins = num_bins
@@ -209,6 +231,7 @@ def spatial_sharded_ih(
             )
         local_h = integral_histogram(
             idx, local_bins, method=method, backend=backend, value_range=None,
+            dtype=dtype,
         )
         carry = local_h[:, local_h.shape[1] - 1, :]         # (b_local, w)
         prefix = exclusive_axis_scan(carry, row_axis, d_rows, scan_impl)
@@ -282,18 +305,20 @@ def iter_banded_sharded_ih(
         band_h=band_h, memory_budget_bytes=memory_budget_bytes,
         num_frames=num_frames, row_multiple=row_multiple,
     )
+    dtype = h_dtype(h, w)               # the frame's, not a band's
 
     def compute_fn(band_img, carry_in):
         if sharding == "bin":
             H_band = bin_sharded_ih(
                 band_img, num_bins, mesh, bin_axis=bin_axis,
                 method=method, backend=backend, value_range=value_range,
+                dtype=dtype,
             )
         else:
             H_band = spatial_sharded_ih(
                 band_img, num_bins, mesh, row_axis=row_axis,
                 method=method, backend=backend, value_range=value_range,
-                scan_impl=scan_impl,
+                scan_impl=scan_impl, dtype=dtype,
             )
         # Band composition is an elementwise add: the carry carries
         # H_band's sharding, so no resharding or collective happens.

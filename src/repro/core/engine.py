@@ -23,7 +23,10 @@ resource mappings (§4's four kernel mappings, §4.4 double-buffering,
     ``integral_histogram``), following Ehsan et al.'s memory-efficient
     design (arXiv:1510.05138);
   * sharding layout when a mesh is given (bin sharding — the paper's
-    multi-GPU scheme — when the bins divide the mesh axis, else spatial).
+    multi-GPU scheme — when the bins divide the mesh axis, else spatial);
+  * H's element type (``core/hdtype``): fp32 below 2**24 pixels, int32
+    past it, where the fused and incremental paths (fp32 kernels) are
+    not taken.
 
 ``HistogramEngine`` composes plan -> compute -> query: ``engine.run``
 returns an ``HSource`` (core/hsource.py) plus the results of any queries,
@@ -34,6 +37,7 @@ or mesh-sharded — is the planner's choice, not the caller's.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Iterable, Iterator
 
 import jax
@@ -42,6 +46,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.core import autotune
 from repro.core import delta as delta_mod
+from repro.core import hdtype
 from repro.core.bands import (
     BandPlan,
     STORAGE_POLICIES,
@@ -56,6 +61,8 @@ from repro.core.hsource import (
     HSource,
     PrefetchedRowsH,
     ShardedH,
+    counting_d2h,
+    to_host,
 )
 
 REPRESENTATIONS = ("dense", "banded", "spilled", "sharded", "fused")
@@ -77,6 +84,15 @@ _AUTO_BATCH_BYTES = 4 << 20
 # predecessor H stops paying and plan() recomputes (tunable per
 # geometry via the "delta_threshold" priors key).
 _DELTA_DIRTY_THRESHOLD = delta_mod.DEFAULT_DIRTY_THRESHOLD
+
+# A run whose H takes more than this share of a device's memory holds one
+# frame's H at a time: two frames in flight, each H with up to half an H
+# of query transients (the window program's lattices), need three H's.
+# Such a run takes one frame, and waits for the previous such run's
+# answers (the last readers of its H) before it computes.  The paper's
+# 8192x8192x128 frame over four v5e chips is 8.59 GB of a chip's
+# 15.75 GB; a 1080p 32-bin frame is 265 MB.
+_ONE_H_DEVICE_SHARE = 1 / 3
 
 
 class PlanValidationError(ValueError):
@@ -146,8 +162,14 @@ class WorkloadSpec:
 
     @property
     def per_frame_h_bytes(self) -> int:
-        """The (num_bins, h, w) fp32 H footprint of one frame."""
+        """The (num_bins, h, w) H footprint of one frame (fp32 and int32
+        elements are both 4 bytes)."""
         return 4 * self.num_bins * self.height * self.width
+
+    @property
+    def h_dtype(self) -> np.dtype:
+        """H's element type for this frame (``core/hdtype``)."""
+        return hdtype.h_dtype(self.height, self.width)
 
 
 # ---------------------------------------------------------------------------
@@ -232,6 +254,7 @@ class ExecutionPlan:
         to the rationale; the default output is unchanged."""
         s = self.spec
         per_frame = s.per_frame_h_bytes
+        elem = "fp32" if s.h_dtype == np.float32 else str(s.h_dtype)
         lines = [
             "ExecutionPlan",
             f"  workload        : {s.height}x{s.width} {s.dtype} frames, "
@@ -239,7 +262,7 @@ class ExecutionPlan:
             + ("open stream" if s.num_frames is None
                else f"{s.num_frames} frame(s)/request"),
             f"  full H          : {per_frame} B/frame "
-            f"({per_frame / 2**20:.1f} MiB fp32)",
+            f"({per_frame / 2**20:.1f} MiB {elem})",
             f"  representation  : {self.representation}",
         ]
         if self.incremental:
@@ -292,7 +315,7 @@ class ExecutionPlan:
                 f"{s.memory_budget_bytes} B budget)"
             )
         if self.storage is None:
-            lines.append("  storage         : device fp32")
+            lines.append(f"  storage         : device {elem}")
         else:
             bound = STORAGE_POLICIES[self.storage][1]
             lines.append(
@@ -397,6 +420,9 @@ def plan(spec: WorkloadSpec) -> ExecutionPlan:
     # skipped for incremental plans — it never stores H, so there is
     # nothing to update next frame; mesh plans reassemble cross-device
     # and are recomputed whole.
+    # The fused and incremental kernels accumulate fp32: a frame whose H
+    # must be int32 takes neither (plancheck refuses them there).
+    fp32 = spec.h_dtype == np.float32
     incremental = False
     if spec.dirty_fraction is not None:
         if not 0.0 <= spec.dirty_fraction <= 1.0:
@@ -405,7 +431,8 @@ def plan(spec: WorkloadSpec) -> ExecutionPlan:
                 f"{spec.dirty_fraction}")
         threshold = float(
             (prior or {}).get("delta_threshold", _DELTA_DIRTY_THRESHOLD))
-        incremental = spec.mesh is None and spec.dirty_fraction <= threshold
+        incremental = (spec.mesh is None and fp32
+                       and spec.dirty_fraction <= threshold)
 
     if spec.query_rows is not None and not incremental:
         rows = spec.query_rows
@@ -428,6 +455,7 @@ def plan(spec: WorkloadSpec) -> ExecutionPlan:
             and spec.storage is None
             and spec.mesh is None
             and fits
+            and fp32
         ):
             return ExecutionPlan(
                 spec=spec, representation="fused", method=spec.method,
@@ -667,11 +695,30 @@ class MultiScaleQuery:
 
 @dataclasses.dataclass
 class EngineResult:
-    """What ``HistogramEngine.run`` hands back."""
+    """What ``HistogramEngine.run`` hands back.  ``d2h_bytes`` counts what
+    the run brought or hands to the host: every byte of H it pulled
+    (``hsource.to_host``) and every byte of the answers."""
 
     plan: ExecutionPlan
     source: HSource
     results: list
+    d2h_bytes: int = 0
+
+
+def answer_bytes(results) -> int:
+    """Bytes of every array in ``results`` (answers of any query kind)."""
+    return sum(int(np.prod(np.shape(x), dtype=np.int64))
+               * np.dtype(x.dtype).itemsize
+               for x in jax.tree_util.tree_leaves(results)
+               if hasattr(x, "dtype"))
+
+
+@functools.lru_cache(maxsize=16)
+def _device_memory_bytes(mesh) -> int | None:
+    """One device's memory, where the backend reports it (not the CPU)."""
+    device = (mesh.devices.flat[0] if mesh is not None
+              else jax.devices()[0])
+    return (device.memory_stats() or {}).get("bytes_limit")
 
 
 def prefetch_rows(source: HSource, queries) -> PrefetchedRowsH | None:
@@ -752,6 +799,7 @@ class HistogramEngine:
         self.last_plan: ExecutionPlan | None = None
         self.last_runtime = None        # FrameRuntime from map_frames
         self.last_verdict = None        # PlanVerdict from validate()
+        self._last_answers = None       # answers of the last one-H run
 
     # -- planning -----------------------------------------------------------
     def spec_for(
@@ -867,7 +915,7 @@ class HistogramEngine:
                 frames, self.num_bins, rows, stats=stats, **kw,
             )
             source = FusedRowsH(
-                rows, np.asarray(R),
+                rows, to_host(R),
                 height=p.spec.height, width=p.spec.width,
             )
             source.last_fused_stats = stats
@@ -1033,11 +1081,18 @@ class HistogramEngine:
         ``engine.compute`` (the H dispatch, with a band stream's row
         prefetch), and one ``engine.query`` (``kind``, ``path``) per
         query: ``path`` is ``compiled`` where a dense H answered through
-        the compiled query programs, ``rows`` where the corner-row
-        protocol answered.
+        the compiled query programs, ``sharded`` where a bin-sharded H
+        answered through them (and a shard_map for regions), ``rows``
+        where the corner-row protocol answered.
+
+        A run whose H takes more than ``_ONE_H_DEVICE_SHARE`` of a
+        device's memory takes one frame, and first waits for the answers
+        of the previous such run, so that frame's H is no longer read
+        when this one is written.  The caller lets go of the previous
+        source (``AnalyticsService`` with no cache does).
         """
         queries = list(queries)
-        with TraceAnnotation("engine.run") as span:
+        with TraceAnnotation("engine.run") as span, counting_d2h() as pulled:
             with TraceAnnotation("engine.plan"):
                 p, prev_source, report = self._plan_run(frames, queries,
                                                         prev)
@@ -1045,8 +1100,11 @@ class HistogramEngine:
                               incremental=int(p.incremental))
             with TraceAnnotation("engine.validate"):
                 self._validate_or_raise(p, queries)
+            one_h = self._one_h_at_a_time(p)
             with TraceAnnotation("engine.update" if p.incremental
                                  else "engine.compute"):
+                if one_h:
+                    self._after_last_answers(frames)
                 if p.incremental:
                     source = self._update(prev_source, frames, report, p)
                 else:
@@ -1054,16 +1112,44 @@ class HistogramEngine:
                 target = source
                 if len(queries) > 1 and isinstance(source, BandedH):
                     target = prefetch_rows(source, queries) or source
-            # A dense H answers through the compiled query programs; the
-            # other representations through the corner-row protocol (a
-            # bin-sharded H's region queries through its shard_map).
-            path = "compiled" if isinstance(target, DenseH) else "rows"
+            # A dense or bin-sharded H answers through the compiled query
+            # programs; the other representations through the corner-row
+            # protocol.
+            path = ("compiled" if isinstance(target, DenseH) else
+                    "sharded" if isinstance(target, ShardedH)
+                    and target.kind == "bin" else "rows")
             results = []
             for q in queries:
                 with TraceAnnotation("engine.query", kind=type(q).__name__,
                                      path=path):
                     results.append(q.apply(target))
-        return EngineResult(plan=p, source=source, results=results)
+            if one_h:
+                self._last_answers = results
+        return EngineResult(plan=p, source=source, results=results,
+                            d2h_bytes=pulled[0] + answer_bytes(results))
+
+    def _one_h_at_a_time(self, p: ExecutionPlan) -> bool:
+        """Does one device's share of this run's H exceed
+        ``_ONE_H_DEVICE_SHARE`` of its memory?  Never where the backend
+        reports no memory (the CPU)."""
+        limit = _device_memory_bytes(p.spec.mesh)
+        if limit is None:
+            return False
+        shards = p.layout.shards_per_group if p.layout is not None else 1
+        per_device = p.spec.per_frame_h_bytes // shards
+        return per_device > _ONE_H_DEVICE_SHARE * limit
+
+    def _after_last_answers(self, frames) -> None:
+        """One frame's H at a time: refuse a stack, and wait until the
+        last such run's answers are computed."""
+        if np.ndim(frames) == 3 and np.shape(frames)[0] > 1:
+            raise ValueError(
+                f"{np.shape(frames)[0]} frames of {np.shape(frames)[1:]} "
+                "need their H at once, and one frame's H takes more than "
+                f"{_ONE_H_DEVICE_SHARE:.0%} of a device: submit them one "
+                "at a time")
+        last, self._last_answers = self._last_answers, None
+        jax.block_until_ready(last)
 
     def _plan_run(self, frames, queries: list, prev):
         """``run``'s plan: the spec with the queries' corner-row union,

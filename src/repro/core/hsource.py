@@ -19,10 +19,16 @@ Every analytics function (``region_histogram``,
 ``rows()`` — a rect touches two rows, a sliding-window field touches two
 strided row lattices, and a multi-scale search touches the union of its
 scales' lattices in a single pass.  Representations override only where
-a genuinely faster path exists (dense strided slices, bin-sharded
-shard_map queries); results are bit-exact either way because all H
-arithmetic is integer-valued (fp32 below 2**24, modular for the integer
-storage policies).
+a genuinely faster path exists (the compiled programs of
+core/region_query.py on a dense or bin-sharded H, which never leave the
+device); results are bit-exact either way because all H arithmetic is
+integer-valued (fp32 below 2**24 pixels, int32 past it — core/hdtype.py
+— and modular for the integer storage policies).  Region histograms
+keep an int32 H's type, so a region of any size is exact.
+
+Every pull of H to the host goes through ``to_host``, which adds its
+bytes to the tally ``counting_d2h`` opens: what ``HistogramEngine.run``
+reports as ``d2h_bytes``.
 
 ``rows()``/``dense()`` return **host** (numpy) arrays by design: on
 jax 0.4.37 ``jnp.concatenate`` over row-sharded device bands silently
@@ -34,6 +40,8 @@ tests/test_distributed.py.
 from __future__ import annotations
 
 import abc
+import contextlib
+import contextvars
 import functools
 import itertools
 import warnings
@@ -42,6 +50,31 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import region_query as rq
+
+
+_D2H_TALLY: contextvars.ContextVar = contextvars.ContextVar(
+    "d2h_tally", default=None)
+
+
+@contextlib.contextmanager
+def counting_d2h():
+    """Tally the bytes ``to_host`` pulls in this context (this thread):
+    yields a one-element list holding the running total."""
+    tally = [0]
+    token = _D2H_TALLY.set(tally)
+    try:
+        yield tally
+    finally:
+        _D2H_TALLY.reset(token)
+
+
+def to_host(x) -> np.ndarray:
+    """``np.asarray(x)``, its bytes added to the open ``counting_d2h``."""
+    out = np.asarray(x)
+    tally = _D2H_TALLY.get()
+    if tally is not None:
+        tally[0] += out.nbytes
+    return out
 
 
 class MissingRowsError(KeyError):
@@ -67,8 +100,10 @@ class HSource(abc.ABC):
     @property
     def exact_region_bound(self) -> int | None:
         """Largest region pixel count a query is guaranteed exact for, or
-        ``None`` when unbounded (fp32 sources are bounded upstream by the
-        2**24 compute-exactness validation)."""
+        ``None`` when unbounded: an H in its frame's element type
+        (core/hdtype.py) holds every count of the frame exactly — fp32
+        below 2**24 pixels, int32 past it.  Only a storage policy that
+        wraps (``SpilledIH``) bounds its regions."""
         return None
 
     @property
@@ -108,7 +143,9 @@ class HSource(abc.ABC):
             )
 
     def region_histogram(self, rects) -> jnp.ndarray:
-        """``region_query.region_histogram`` semantics; returns fp32."""
+        """``region_query.region_histogram`` semantics: int32 from an
+        int32 H, fp32 otherwise (the storage policies' modular counts are
+        true counts after the four-corner sum)."""
         rects = np.asarray(rects)
         area = (rects[..., 2] - rects[..., 0] + 1) * (
             rects[..., 3] - rects[..., 1] + 1
@@ -119,7 +156,7 @@ class HSource(abc.ABC):
         out = rq.compressed_region_histogram(
             jnp.asarray(Hc), jnp.asarray(needed), jnp.asarray(rects)
         )
-        return out.astype(jnp.float32)
+        return out if out.dtype == jnp.int32 else out.astype(jnp.float32)
 
     def _window_lattices(self, window, stride):
         """The two corner-row lattices of the regular window grid."""
@@ -281,7 +318,7 @@ class DenseH(HSource):
             * self.H.dtype.itemsize
 
     def rows(self, row_ids) -> np.ndarray:
-        return np.asarray(self.H[..., np.asarray(row_ids), :])
+        return to_host(self.H[..., np.asarray(row_ids), :])
 
     def dense(self):
         return self.H
@@ -388,13 +425,13 @@ class BandedH(HSource):
             if out is None:
                 out = np.zeros(
                     band.H.shape[:-2] + (len(row_ids), band.H.shape[-1]),
-                    np.float32,
+                    band.H.dtype,
                 )
             num_bands = band.num_bands
             sel = (row_ids >= band.r0) & (row_ids < band.r1)
-            # Host-side assembly: np.asarray pulls the (possibly sharded)
+            # Host-side assembly: to_host pulls the (possibly sharded)
             # band off device before any indexing/concatenation happens.
-            Hb = np.asarray(band.H)
+            Hb = to_host(band.H)
             peak_band = max(peak_band, Hb.nbytes)
             if sel.any():
                 out[..., sel, :] = Hb[..., row_ids[sel] - band.r0, :]
@@ -407,7 +444,7 @@ class BandedH(HSource):
         """Assemble full H host-side (np.concatenate over host bands —
         never ``jnp.concatenate`` over possibly-sharded device bands)."""
         return jnp.asarray(np.concatenate(
-            [np.asarray(band.H) for band in self._take_stream()], axis=-2,
+            [to_host(band.H) for band in self._take_stream()], axis=-2,
         ))
 
     def update_bands(self, next_frame, report, *, recompute,
@@ -620,13 +657,15 @@ def _rows_gather(mesh, kind, lead, bin_axis, row_axis, local_h):
 
 @functools.lru_cache(maxsize=64)
 def _region_sharded(mesh, h_lead, rects_ndim, bin_axis):
-    """Jitted (H, rects) -> per-bin-shard region histograms (bin kind)."""
+    """Jitted (H, rects) -> per-bin-shard region histograms (bin kind),
+    read by ``region_query.regions_by_tiles``: no gather, so no copy of
+    a chip's share of H."""
     import jax
     from jax.sharding import PartitionSpec as P
     from jax import shard_map
 
     fn = shard_map(
-        lambda h_local, r: rq.region_histogram(h_local, r),
+        rq.regions_by_tiles,
         mesh=mesh,
         in_specs=(
             P(*([None] * h_lead), bin_axis, None, None), P(),
@@ -637,15 +676,31 @@ def _region_sharded(mesh, h_lead, rects_ndim, bin_axis):
     return jax.jit(fn)
 
 
+@functools.lru_cache(maxsize=16)
+def _replicated(mesh):
+    """Jitted copy of an array onto every device of ``mesh``: the gather
+    is an all-gather inside one compiled program, over the chips' links
+    (a ``device_put`` between two shardings is left to the runtime)."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return jax.jit(lambda x: x, out_shardings=NamedSharding(mesh, P()))
+
+
 class ShardedH(HSource):
     """A mesh-sharded dense H (core/distributed.py).
 
-    ``kind="bin"`` (the paper's multi-GPU scheme) keeps region queries
-    device-side and embarrassingly parallel via shard_map.  ``rows()``
-    gathers corner rows device-side for both kinds: bin shards index
-    their (unsharded) row axis locally, row shards mask-select the rows
-    they own and a ``psum`` assembles the slab — so the only readback is
-    the (.., b, k, w) slab itself, never the whole H.  A device-side
+    ``kind="bin"`` (the paper's multi-GPU scheme) answers every query on
+    the devices: region queries through a shard_map, embarrassingly
+    parallel over bins; window histograms, likelihood maps and the
+    multi-scale search through the compiled dense programs of
+    core/region_query.py applied to the global sharded array, where the
+    compiler keeps each chip on its own bins; the one collective gathers
+    the window field onto every chip for the score.  ``rows()`` gathers
+    corner rows device-side for both kinds: bin shards index their
+    (unsharded) row axis locally, row shards mask-select the rows they
+    own and a ``psum`` assembles the slab — so the only readback is the
+    (.., b, k, w) slab itself, never the whole H.  A device-side
     ``concatenate`` over shards would be the jax-0.4.37 hazard; the
     gather uses take/where/psum only."""
 
@@ -687,12 +742,13 @@ class ShardedH(HSource):
     def rows(self, row_ids) -> np.ndarray:
         row_ids = np.asarray(row_ids)
         if row_ids.size == 0:
-            return np.asarray(self.H)[..., row_ids, :]
+            return np.zeros(self.lead + (self.num_bins, 0, self.width),
+                            self.H.dtype)
         if self.kind == "spatial" and self.height % self.mesh.shape[self.row_axis]:
             # Uneven row shards cannot compute local offsets statically;
             # fall back to the whole-H host pull (engine plans never
             # produce this — plan validation requires divisibility).
-            return np.asarray(self.H)[..., row_ids, :]
+            return to_host(self.H)[..., row_ids, :]
         return self._rows_device(row_ids)
 
     def _rows_device(self, row_ids: np.ndarray) -> np.ndarray:
@@ -708,10 +764,10 @@ class ShardedH(HSource):
                    else self.height // self.mesh.shape[self.row_axis])
         fn = _rows_gather(self.mesh, self.kind, lead,
                           self.bin_axis, self.row_axis, local_h)
-        return np.asarray(fn(self.H, rid))
+        return to_host(fn(self.H, rid))
 
     def dense(self):
-        return jnp.asarray(np.asarray(self.H))
+        return jnp.asarray(to_host(self.H))
 
     def region_histogram(self, rects) -> jnp.ndarray:
         if self.kind != "bin":
@@ -722,6 +778,38 @@ class ShardedH(HSource):
         # shard_map per (mesh, geometry), rects as a dynamic argument.
         fn = _region_sharded(self.mesh, h_lead, rects.ndim, self.bin_axis)
         return fn(self.H, rects)
+
+    def sliding_window_histograms(
+        self, window, stride: int = 1, *, stats: dict | None = None
+    ) -> jnp.ndarray:
+        if self.kind != "bin":
+            return super().sliding_window_histograms(window, stride,
+                                                     stats=stats)
+        return rq.sliding_window_histograms(self.H, window, stride,
+                                            stats=stats)
+
+    def likelihood_map(self, target_hist, window, metric, stride: int = 1,
+                       *, stats: dict | None = None):
+        if self.kind != "bin":
+            return super().likelihood_map(target_hist, window, metric,
+                                          stride, stats=stats)
+        hists = self.sliding_window_histograms(window, stride, stats=stats)
+        # Gathered onto every chip before the score: each then sums all
+        # bins in the order one device does, so the map is bit-identical
+        # to a DenseH's (a sum of per-chip partial sums would round
+        # differently).
+        return rq.score(_replicated(self.mesh)(hists), target_hist, metric)
+
+    def multi_scale_search(self, target_hist, windows, metric,
+                           stride: int = 1):
+        if self.kind != "bin":
+            return super().multi_scale_search(target_hist, windows, metric,
+                                              stride)
+        maps = [self.likelihood_map(target_hist, wnd, metric, stride)
+                for wnd in windows]
+        best_rect, best_score = rq.reduce_scale_maps(maps, windows, stride,
+                                                     self.lead)
+        return best_rect, best_score, maps
 
 
 def as_hsource(H) -> HSource:

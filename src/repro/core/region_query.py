@@ -21,10 +21,12 @@ program per query shape, so a query costs a dispatch or two, not one per
 ``jax.numpy`` op:
 
   * ``_dense_windows`` — the window histograms, ``window`` and ``stride``
-    static.  The regular window grid puts every corner of every window
-    on a strided lattice of H, so the whole (n_rows, n_cols) field of
-    Eq.-2 queries is strided ``lax.slice`` calls (two row lattices,
-    then four corner lattices along the columns) combined elementwise:
+    static, as fp32 (an int32 H's field is cast after the four-corner
+    sum, where every value is at most the window's area).  The regular
+    window grid puts every corner of every window on a strided lattice
+    of H, so the whole (n_rows, n_cols) field of Eq.-2 queries is
+    strided ``lax.slice`` calls (two row lattices, then four corner
+    lattices along the columns) combined elementwise:
     strided loads, no index arrays, no gather.  Strided indexing
     (``H[..., r::s, c::s]``) would not do: jax turns a strided index
     into iota/mul/add index arrays and a ``gather``, which eagerly reads
@@ -35,8 +37,17 @@ program per query shape, so a query costs a dispatch or two, not one per
     program of its own: fused into the window program, XLA reorders
     the metric's float arithmetic.
   * ``_dense_regions`` — ``region_histogram`` with the rects traced
-    (one compile per rect count).  Arbitrary corners stay a gather,
+    (one compile per rect count), in H's element type, so an int32 H's
+    regions of any size are exact.  Arbitrary corners stay a gather,
     inside the one program.
+
+The window program takes any jax array H, a global array sharded over
+its bins across chips included (``ShardedH``): the compiler keeps each
+chip on its own bins, with no collective.  A gather of arbitrary
+corners makes the TPU compiler lay the whole H out again, bins minor,
+which a chip holding 8.59 GB of the paper's 8192x8192x128 H cannot
+spare; ``regions_by_tiles`` answers region queries there with no gather
+and no copy of H.
 
 Called under ``jit`` or ``scan`` (``FragmentTracker``), the programs
 inline into the caller's.  ``sliding_window_histograms(impl="gather")``
@@ -107,6 +118,40 @@ def _four_corners(H: jnp.ndarray, rects: jnp.ndarray) -> jnp.ndarray:
 def _dense_regions(H: jnp.ndarray, rects: jnp.ndarray) -> jnp.ndarray:
     """``_four_corners`` as one program; compiles once per rect shape."""
     return _four_corners(H, rects)
+
+
+def regions_by_tiles(H: jnp.ndarray, rects: jnp.ndarray) -> jnp.ndarray:
+    """``region_histogram`` of H (..., b, h, w) without a gather: a loop
+    over the rects reads each corner as the masked sum of the (8, 128)
+    tile of H that holds it, over all bins at once.  Slices of H's own
+    tiles keep H in its layout, where a gather of single points would
+    have the compiler copy the whole H, bins minor.  The masked sum adds
+    zeros to the one value, and the four corners combine in Eq. 2's
+    order, so the result is bit-identical to ``_four_corners``."""
+    lead = H.shape[:-2]                       # (..., b)
+    h, w = H.shape[-2:]
+    th, tw = min(8, h), min(128, w)
+    zero = jnp.zeros((), H.dtype)
+
+    def corner(r, c):
+        rr, cc = jnp.maximum(r, 0), jnp.maximum(c, 0)
+        r0 = jnp.minimum(rr - rr % th, h - th)
+        c0 = jnp.minimum(cc - cc % tw, w - tw)
+        tile = lax.dynamic_slice(H, (0,) * len(lead) + (r0, c0),
+                                 lead + (th, tw))
+        hit = ((lax.broadcasted_iota(jnp.int32, (th, tw), 0) == rr - r0)
+               & (lax.broadcasted_iota(jnp.int32, (th, tw), 1) == cc - c0))
+        v = jnp.sum(jnp.where(hit, tile, zero), axis=(-2, -1))
+        return jnp.where((r >= 0) & (c >= 0), v, zero)
+
+    def one(rect):
+        r0, c0, r1, c1 = rect[0], rect[1], rect[2], rect[3]
+        return (corner(r1, c1) - corner(r0 - 1, c1)
+                - corner(r1, c0 - 1) + corner(r0 - 1, c0 - 1))
+
+    out = lax.map(one, rects.reshape(-1, 4))              # (R, ..., b)
+    out = jnp.moveaxis(out, 0, -2)                         # (..., R, b)
+    return out.reshape(lead[:-1] + rects.shape[:-1] + lead[-1:])
 
 
 def region_histogram(H: jnp.ndarray, rects: jnp.ndarray) -> jnp.ndarray:
@@ -202,8 +247,10 @@ def _dense_windows(
     c = _zero_first(_lattice(bottom, -3, s - 1, n_c - 1, s), -3)
     a = _zero_first(_lattice(top, -3, s - 1, n_c - 1, s), -3)
     # Same association order as the gather path (d - b - c + a) so the
-    # fp32 arithmetic is bit-identical, not just allclose.
-    return jnp.moveaxis(d - b - c + a, -1, -3)               # (..., n_r, n_c, b)
+    # fp32 arithmetic is bit-identical, not just allclose.  An int32 H's
+    # field becomes fp32 only after the sum (a no-op for an fp32 H).
+    field = (d - b - c + a).astype(jnp.float32)
+    return jnp.moveaxis(field, -1, -3)                       # (..., n_r, n_c, b)
 
 
 @functools.partial(jax.jit, static_argnames=("metric",))
@@ -256,7 +303,8 @@ def sliding_window_histograms(
     if n_r <= 0 or n_c <= 0:
         # window larger than the frame on some axis: no positions
         return jnp.zeros(
-            H.shape[:-3] + (max(n_r, 0), max(n_c, 0), H.shape[-3]), H.dtype
+            H.shape[:-3] + (max(n_r, 0), max(n_c, 0), H.shape[-3]),
+            jnp.float32,
         )
     if impl == "slice":
         return _dense_windows(H, window=tuple(int(v) for v in window),

@@ -36,9 +36,10 @@ from repro.core.binning import bin_indices, one_hot_bins
 # Band-carry composition.  An integral histogram is a prefix sum over rows,
 # so the H of rows [r0, r1) of a frame equals the local H of that band plus
 # the full-frame H's row r0-1 — an (..., b, w) aggregate, the WF-TiS column
-# carry lifted out of the kernel.  All arithmetic is integer-valued fp32
-# (exact below 2**24), so post-adding the carry is bit-identical to seeding
-# the scan with it; core/bands.py streams whole frames through this.
+# carry lifted out of the kernel.  All arithmetic is integer-valued (fp32
+# below 2**24 pixels, int32 past it: core/hdtype.py), so post-adding the
+# carry is bit-identical to seeding the scan with it; core/bands.py
+# streams whole frames through this.
 # ---------------------------------------------------------------------------
 def apply_carry(H: jnp.ndarray, carry_in: jnp.ndarray | None) -> jnp.ndarray:
     """Compose a band's local H (..., b, bh, w) with the (..., b, w)
@@ -132,6 +133,7 @@ def _wf_tis_single(
     value_range: int,
     tile: int,
     carry_in: jnp.ndarray | None = None,
+    dtype=jnp.float32,
 ) -> jnp.ndarray:
     idx = bin_indices(image, num_bins, value_range)
     h, w = image.shape
@@ -142,18 +144,20 @@ def _wf_tis_single(
 
     def strip_step(col_carry, idx_strip):
         # col_carry: (b, w) running column sums of everything above.
+        # The strip's own scans are fp32 and exact (at most th x w); the
+        # strip takes H's type before the carry joins it, as in the kernel.
         q = one_hot_bins(idx_strip, num_bins)                # (b, th, w)
         hs = jnp.cumsum(q, axis=2)                           # horizontal scan
         vs = jnp.cumsum(hs, axis=1)                          # vertical within strip
-        out = vs + col_carry[:, None, :]
+        out = vs.astype(dtype) + col_carry[:, None, :]
         return out[:, -1, :], out                            # new carry, strip H
 
     # A band's carry_in seeds the scan exactly where the previous band's
     # bottom row left off — the natural statement of band streaming.
     init = (
-        jnp.zeros((num_bins, w), dtype=jnp.float32)
+        jnp.zeros((num_bins, w), dtype=dtype)
         if carry_in is None
-        else carry_in.astype(jnp.float32)
+        else carry_in.astype(dtype)
     )
     _, strips = jax.lax.scan(strip_step, init, idx_strips)
     return jnp.moveaxis(strips, 1, 0).reshape(num_bins, hp, w)[:, :h, :]
@@ -165,16 +169,17 @@ def wf_tis(
     value_range: int = 256,
     tile: int = 128,
     carry_in: jnp.ndarray | None = None,
+    dtype=jnp.float32,
 ) -> jnp.ndarray:
+    """WF-TiS; ``dtype`` is H's element type, float32 or int32
+    (``core/hdtype``), the same as the Pallas kernel's."""
     if image.ndim == 3:  # frame stack: widen the strip scan's carry to (n, b, w)
         if carry_in is None:
-            return jax.vmap(
-                lambda im: _wf_tis_single(im, num_bins, value_range, tile)
-            )(image)
-        return jax.vmap(
-            lambda im, c: _wf_tis_single(im, num_bins, value_range, tile, c)
-        )(image, carry_in)
-    return _wf_tis_single(image, num_bins, value_range, tile, carry_in)
+            return jax.vmap(lambda im: _wf_tis_single(
+                im, num_bins, value_range, tile, dtype=dtype))(image)
+        return jax.vmap(lambda im, c: _wf_tis_single(
+            im, num_bins, value_range, tile, c, dtype))(image, carry_in)
+    return _wf_tis_single(image, num_bins, value_range, tile, carry_in, dtype)
 
 
 METHODS = {"cw_b": cw_b, "cw_sts": cw_sts, "cw_tis": cw_tis, "wf_tis": wf_tis}

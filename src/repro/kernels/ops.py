@@ -27,7 +27,7 @@ Band streaming (core/bands.py) enters here through two knobs:
                         restricted to the slice's rows.  Threads into the
                         Pallas kernels' VMEM carry chain and the jnp
                         wf_tis scan seed; bit-exact either way (all
-                        arithmetic is integer-valued fp32).
+                        arithmetic is integer-valued).
   memory_budget_bytes — cap on the per-dispatch H footprint: frames whose
                         (n, b, h, w) output exceeds it are computed band
                         by band with the carry threaded between dispatches
@@ -35,6 +35,10 @@ Band streaming (core/bands.py) enters here through two knobs:
                         (one-hot masks, transposes, scan intermediates) to
                         a band; use core/bands.py directly when even the
                         assembled H must never materialize.
+
+H's element type is ``core/hdtype.h_dtype`` of the frame: float32 below
+2**24 pixels, int32 at or above it, where only ``wf_tis`` runs.  A band
+of a larger frame names the frame's type with ``dtype``.
 """
 
 from __future__ import annotations
@@ -47,6 +51,7 @@ import numpy as np
 
 from repro.core import scans
 from repro.core.binning import PAD_BIN, bin_indices
+from repro.core.hdtype import h_dtype
 from repro.kernels import cw_tis, delta_apply as delta_apply_mod, \
     fused_rows, wf_tis
 from repro.kernels.cw_tis import cw_tis_pallas
@@ -91,7 +96,7 @@ def _on_tpu() -> bool:
     jax.jit,
     static_argnames=(
         "num_bins", "method", "backend", "tile", "bin_block", "use_mxu",
-        "interpret", "value_range",
+        "interpret", "value_range", "dtype",
     ),
 )
 def _integral_histogram_jit(
@@ -106,13 +111,16 @@ def _integral_histogram_jit(
     use_mxu: bool,
     interpret: bool,
     value_range: int,
+    dtype: np.dtype,
 ) -> jnp.ndarray:
-    """The jit'd core: backend already resolved, inputs already validated."""
+    """The jit'd core: backend already resolved, inputs already validated
+    (an int32 ``dtype`` comes with ``method="wf_tis"``)."""
     if backend == "jnp":
         if method == "wf_tis":
             # Native carry seeding: the band scan starts from carry_in.
             return scans.wf_tis(
-                image, num_bins, value_range, tile=tile, carry_in=carry_in
+                image, num_bins, value_range, tile=tile, carry_in=carry_in,
+                dtype=dtype,
             )
         kw = {} if method in ("cw_b", "cw_sts") else {"tile": tile}
         H = scans.METHODS[method](image, num_bins, value_range, **kw)
@@ -128,10 +136,11 @@ def _integral_histogram_jit(
         # no mass and padded columns are cropped, so zero-fill is exact.
         pad = [(0, 0)] * (carry_in.ndim - 2)
         pad += [(0, nb_pad - num_bins), (0, (-w) % tile)]
-        carry = jnp.pad(carry_in.astype(jnp.float32), pad)
+        carry = jnp.pad(carry_in.astype(dtype), pad)
+    kw = {"dtype": dtype} if method == "wf_tis" else {}
     out = PALLAS_METHODS[method](
         idx, nb_pad, tile=tile, bin_block=bin_block, use_mxu=use_mxu,
-        interpret=interpret, carry=carry,
+        interpret=interpret, carry=carry, **kw,
     )
     return out[..., :num_bins, :h, :w]
 
@@ -149,11 +158,13 @@ def integral_histogram(
     value_range: int = 256,
     carry_in: jnp.ndarray | None = None,
     memory_budget_bytes: int | None = None,
+    dtype=None,
 ) -> jnp.ndarray:
     """Inclusive integral histogram of a frame or an (n, h, w) frame stack.
 
-    See the module docstring for ``carry_in`` (band composition) and
-    ``memory_budget_bytes`` (auto-banding).
+    See the module docstring for ``carry_in`` (band composition),
+    ``memory_budget_bytes`` (auto-banding) and ``dtype`` (H's element
+    type; ``None`` is ``h_dtype`` of the image's own shape).
     """
     if image.ndim not in (2, 3):
         raise ValueError(f"expected (h, w) or (n, h, w), got {image.shape}")
@@ -161,6 +172,11 @@ def integral_histogram(
         raise ValueError(f"unknown backend {backend!r}")
     if method not in scans.METHODS:
         raise ValueError(f"unknown method {method!r}")
+    dtype = h_dtype(*image.shape[-2:]) if dtype is None else np.dtype(dtype)
+    if dtype != np.float32 and method != "wf_tis":
+        raise ValueError(
+            f"an {dtype} H (frames of 2**24 pixels or more) is accumulated "
+            f"only by wf_tis; {method!r} holds float32 and would round")
     if backend == "pallas" and method not in PALLAS_METHODS:
         # An explicit backend request must not silently degrade: only
         # "auto" may fall back to the jnp scans.
@@ -198,13 +214,13 @@ def integral_histogram(
                 image, num_bins, plan=p.band_plan, carry_in=carry_in,
                 method=method, backend=p.backend, tile=tile,
                 bin_block=bin_block, use_mxu=use_mxu, interpret=interpret,
-                value_range=value_range,
+                value_range=value_range, dtype=dtype,
             )
 
     return _integral_histogram_jit(
         image, carry_in, num_bins, method=method, backend=backend,
         tile=tile, bin_block=bin_block, use_mxu=use_mxu,
-        interpret=interpret, value_range=value_range,
+        interpret=interpret, value_range=value_range, dtype=dtype,
     )
 
 
@@ -341,6 +357,7 @@ def fused_corner_rows(
                 band, carry, num_bins, method=method, backend="jnp",
                 tile=tile, bin_block=bin_block, use_mxu=use_mxu,
                 interpret=interpret, value_range=value_range,
+                dtype=np.dtype(np.float32),
             )
             carry = Hb[..., Hb.shape[-2] - 1, :]
             local = rows[(rows >= b * tile) & (rows < (b + 1) * tile)]

@@ -50,6 +50,7 @@ class KernelGeometry:
     num_bins: int
     tile: int = 128
     bin_block: int = 8
+    dtype: str = "float32"      # H's element type (core/hdtype.h_dtype)
 
     @property
     def h_pad(self) -> int:
@@ -93,6 +94,7 @@ class KernelGeometry:
             num_bins=min(self.nbb, max_blocks) * self.bin_block,
             tile=self.tile,
             bin_block=self.bin_block,
+            dtype=self.dtype,
         )
 
 

@@ -29,7 +29,14 @@ TPU adaptation (DESIGN.md §2):
     extra state.  One pallas_call for the whole stack amortizes dispatch
     exactly like the paper's dual-stream frame pipeline (§4.4).
 
-Accumulation is fp32 (exact for counts < 2**24; all supported planes).
+Accumulation: the in-tile row and column scans are float32 on the MXU at
+``HIGHEST``; their values are at most tile x padded width (2**20 for a
+128-row strip of an 8192-wide frame), so they are exact.  The column
+carry, the band carry-in and the output are H's element type
+(``core/hdtype.h_dtype``): float32 below 2**24 pixels, where the kernel
+is what it always was, and int32 at or above it, where the tile's result
+is converted to int32 before the column carry is added, so every count
+of the frame is exact.
 """
 
 from __future__ import annotations
@@ -65,6 +72,7 @@ def kernel_specs(geom: KernelGeometry) -> tuple[KernelSpec, ...]:
     n, nth, ntw, nbb = geom.n, geom.nth, geom.ntw, geom.nbb
     t, bb_blk = geom.tile, geom.bin_block
     hp, wp, nbp = geom.h_pad, geom.w_pad, geom.nb_pad
+    dt = geom.dtype
 
     def reads(g):
         edges = []
@@ -87,15 +95,15 @@ def kernel_specs(geom: KernelGeometry) -> tuple[KernelSpec, ...]:
                 Operand("idx", (n, hp, wp), (1, t, t),
                         lambda f, ih, iw, bb: (f, ih, iw), dtype="int32"),
                 Operand("carry", (n, nbp, wp), (1, bb_blk, t),
-                        lambda f, ih, iw, bb: (f, bb, iw)),
+                        lambda f, ih, iw, bb: (f, bb, iw), dtype=dt),
             ),
             out_specs=(
                 Operand("out", (n, nbp, hp, wp), (1, bb_blk, t, t),
-                        lambda f, ih, iw, bb: (f, bb, ih, iw)),
+                        lambda f, ih, iw, bb: (f, bb, ih, iw), dtype=dt),
             ),
             scratch=(
                 Scratch("row_carry", (nbb, bb_blk, t)),
-                Scratch("col_carry", (nbb, bb_blk, wp)),
+                Scratch("col_carry", (nbb, bb_blk, wp), dtype=dt),
             ),
             carry_reads=reads,
             carry_writes=writes,
@@ -162,10 +170,10 @@ def _left_matmul(a: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
 
 def _wf_tis_kernel(
     idx_ref,      # (1, TH, TW) int32 bin indices (PAD_BIN=-1 outside the image)
-    carry_ref,    # (1, BIN_BLOCK, TW) fp32 band carry-in (zeros = topmost band)
-    out_ref,      # (1, BIN_BLOCK, TH, TW) fp32 integral histogram block
-    row_carry,    # VMEM scratch (NBB, BIN_BLOCK, TH) — right-edge carries
-    col_carry,    # VMEM scratch (NBB, BIN_BLOCK, W_PAD) — bottom-edge carries
+    carry_ref,    # (1, BIN_BLOCK, TW) H-typed band carry-in (zeros = topmost band)
+    out_ref,      # (1, BIN_BLOCK, TH, TW) H-typed integral histogram block
+    row_carry,    # VMEM scratch (NBB, BIN_BLOCK, TH) fp32 — right-edge carries
+    col_carry,    # VMEM scratch (NBB, BIN_BLOCK, W_PAD) H-typed — bottom-edge carries
     *,
     bin_block: int,
     tile_w: int,
@@ -207,10 +215,12 @@ def _wf_tis_kernel(
     # strip above).  On the first strip — of every frame, since the raster
     # restarts there — it is seeded from the band carry-in instead of zero:
     # the host-level band decomposition (core/bands.py) enters the kernel
-    # here, exactly where the VMEM carry chain begins.
+    # here, exactly where the VMEM carry chain begins.  The strip's own
+    # scan is exact in fp32; it takes H's type before the carry joins it
+    # (a no-op for an fp32 H).
     cols = pl.dslice(iw * tile_w, tile_w)
     cc = jnp.where(ih == 0, carry_ref[0], col_carry[bb, :, cols])
-    vs = vs + cc[:, None, :]
+    vs = vs.astype(out_ref.dtype) + cc[:, None, :]
     col_carry[bb, :, cols] = vs[:, th - 1, :]              # new bottom edge
 
     out_ref[0] = vs
@@ -225,6 +235,7 @@ def wf_tis_pallas(
     use_mxu: bool = True,
     interpret: bool = False,
     carry: jnp.ndarray | None = None,
+    dtype=jnp.float32,
 ) -> jnp.ndarray:
     """Fused WF-TiS integral histogram.
 
@@ -233,13 +244,14 @@ def wf_tis_pallas(
         h % tile == 0 and w % tile == 0 (padding uses PAD_BIN so it matches
         no bin).
       num_bins: padded bin count, multiple of ``bin_block``.
-      carry: optional ([n,] num_bins, w) fp32 band carry-in — the bottom row
+      carry: optional ([n,] num_bins, w) band carry-in — the bottom row
         of the band above when this call computes one row band of a larger
         frame (core/bands.py).  ``None`` means a frame top (zero carry).
+      dtype: H's element type, float32 or int32 (``core/hdtype``).
 
     Returns:
-      (num_bins, h, w) fp32 inclusive integral histogram for a single
-      frame, (n, num_bins, h, w) for a frame stack.
+      (num_bins, h, w) inclusive integral histogram of ``dtype`` for a
+      single frame, (n, num_bins, h, w) for a frame stack.
     """
     squeeze = idx.ndim == 2
     if squeeze:
@@ -251,8 +263,9 @@ def wf_tis_pallas(
         raise ValueError(f"padded image {h}x{w} not divisible by tile {tile}")
     if num_bins % bin_block:
         raise ValueError(f"{num_bins} bins not divisible by bin_block {bin_block}")
+    dtype = jnp.dtype(dtype)
     if carry is None:
-        carry = jnp.zeros((n, num_bins, w), jnp.float32)
+        carry = jnp.zeros((n, num_bins, w), dtype)
     if carry.shape != (n, num_bins, w):
         raise ValueError(
             f"carry shape {carry.shape} != {(n, num_bins, w)} (frames, "
@@ -265,7 +278,7 @@ def wf_tis_pallas(
     )
     scratch = [
         pltpu.VMEM((nbb, bin_block, tile), jnp.float32),  # row carries
-        pltpu.VMEM((nbb, bin_block, w), jnp.float32),     # column carries
+        pltpu.VMEM((nbb, bin_block, w), dtype),           # column carries
     ]
     out = pl.pallas_call(
         kernel,
@@ -279,9 +292,9 @@ def wf_tis_pallas(
         out_specs=pl.BlockSpec(
             (1, bin_block, tile, tile), lambda f, ih, iw, bb: (f, bb, ih, iw)
         ),
-        out_shape=jax.ShapeDtypeStruct((n, num_bins, h, w), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n, num_bins, h, w), dtype),
         scratch_shapes=scratch,
         interpret=interpret,
         name="wf_tis",
-    )(idx, carry.astype(jnp.float32))
+    )(idx, carry.astype(dtype))
     return out[0] if squeeze else out

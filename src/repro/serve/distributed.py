@@ -308,7 +308,7 @@ class DistributedAnalyticsService:
         agg: dict = {
             k: sum(p[k] for p in per)
             for k in ("requests", "completed", "engine_runs", "cache_hits",
-                      "coalesced", "updated", "recomputed")
+                      "coalesced", "updated", "recomputed", "d2h_bytes")
         }
         with self._lock:
             rejected = self._rejected

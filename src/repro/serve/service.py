@@ -33,8 +33,9 @@ This module adds the request-level scheduler on top:
     resource-constrained serving posture: fail loudly, never thrash).
   * **Stats** — per-request latency (p50/p95 over the most recent
     4096 requests), throughput, cache hit rate, coalescing ratio,
-    engine-run count (``service.stats.snapshot()``) — what
-    benchmarks/bench_serve.py reports.
+    engine-run count, and ``d2h_bytes``: the bytes every answer and
+    every pull of H brought to the host (``service.stats.snapshot()``)
+    — what benchmarks/bench_serve.py reports.
   * **Spans** — ``jax.profiler.TraceAnnotation`` at each boundary, on
     the profiler's host line of the worker thread: ``service.wait``
     (blocked on an empty queue), ``service.batch`` (one drained batch:
@@ -93,6 +94,7 @@ class ServiceStats:
     updated: int = 0                # engine runs via incremental update
     recomputed: int = 0             # engine runs via full recompute
     completed: int = 0              # requests answered
+    d2h_bytes: int = 0              # answers and pulls of H to the host
     latencies_s: collections.deque = dataclasses.field(
         default_factory=lambda: collections.deque(maxlen=LATENCY_WINDOW))
     started_at: float = dataclasses.field(default_factory=time.perf_counter)
@@ -122,6 +124,7 @@ class ServiceStats:
             "recomputed": self.recomputed,
             "hit": self.cache_hits,
             "update_ratio": self.updated / max(self.engine_runs, 1),
+            "d2h_bytes": self.d2h_bytes,
             "requests_per_s": done / wall if wall > 0 else 0.0,
             "latency_p50_s": float(lat[int(0.50 * (n - 1))]) if n else 0.0,
             "latency_p95_s": float(lat[int(0.95 * (n - 1))]) if n else 0.0,
@@ -288,6 +291,7 @@ class AnalyticsService:
         out = self._engine.run(frame, queries, prev=prev)
         incremental = getattr(out.plan, "incremental", False)
         with self._lock:
+            self.stats.d2h_bytes += out.d2h_bytes
             self.stats.engine_runs += 1
             if incremental:
                 self.stats.updated += 1
@@ -304,8 +308,9 @@ class AnalyticsService:
         """Answer every request of one frame group; returns the results
         in group order and the group's outcome (``_source_for``'s, or
         ``"refetch"`` when a cache hit had to re-run the engine)."""
-        from repro.core.engine import prefetch_rows
-        from repro.core.hsource import BandedH, MissingRowsError
+        from repro.core.engine import answer_bytes, prefetch_rows
+        from repro.core.hsource import (BandedH, MissingRowsError,
+                                        counting_d2h)
 
         queries = [p.query for p in group]
         source, results, outcome = self._source_for(frame_ref, queries)
@@ -313,11 +318,14 @@ class AnalyticsService:
             # Cache hit: apply the queries to the cached source, sharing
             # one corner-row prefetch when the source streams (the same
             # union the engine does for a fresh multi-query run).
-            target = source
-            if len(queries) > 1 and isinstance(source, BandedH):
-                target = prefetch_rows(source, queries) or source
             try:
-                results = [q.apply(target) for q in queries]
+                with counting_d2h() as pulled:
+                    target = source
+                    if len(queries) > 1 and isinstance(source, BandedH):
+                        target = prefetch_rows(source, queries) or source
+                    results = [q.apply(target) for q in queries]
+                with self._lock:
+                    self.stats.d2h_bytes += pulled[0] + answer_bytes(results)
             except MissingRowsError:
                 # A fused cache entry holds ONLY its own request's corner
                 # rows; a hit that reads outside that set has no H to
@@ -328,6 +336,7 @@ class AnalyticsService:
                 out = self._engine.run(self._frame(frame_ref), queries)
                 results = out.results
                 with self._lock:
+                    self.stats.d2h_bytes += out.d2h_bytes
                     self.stats.engine_runs += 1
                     self.stats.recomputed += 1
                     if self.cache_size:

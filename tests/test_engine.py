@@ -185,13 +185,13 @@ ExecutionPlan
 GOLDEN_64MB_128 = """\
 ExecutionPlan
   workload        : 8192x8192 uint8 frames, 128 bins, 1 frame(s)/request
-  full H          : 34359738368 B/frame (32768.0 MiB fp32)
+  full H          : 34359738368 B/frame (32768.0 MiB int32)
   representation  : banded
   method/backend  : wf_tis / jnp
   tile/bin_block  : 128 / 8
   microbatch      : 1 frame(s)/dispatch
   bands           : 128 x 64 rows (268435456 B/band <= 268435456 B budget)
-  storage         : device fp32
+  storage         : device int32
   sharding        : none"""
 
 

@@ -98,3 +98,29 @@ def test_dense_window_program_compiles_for_v5e(one_chip, no_compile_cache):
                              sharding=one_chip)
     compiled = rq._dense_windows.lower(H, window=(64, 64), stride=2).compile()
     assert "gather" not in compiled.as_text()
+
+
+def test_int32_wf_tis_compiles_for_v5e(one_chip, no_compile_cache):
+    """A band of the 8192-wide frame with an int32 carry past 2**24
+    compiles to the int32 kernel."""
+    n, h, w, bins = SHAPES["band_1024x8192x128"]
+    frames = jax.ShapeDtypeStruct((n, h, w), jnp.uint8, sharding=one_chip)
+    carry = jax.ShapeDtypeStruct((n, bins, w), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lambda f, c: ops.integral_histogram(
+        f, bins, backend="pallas", carry_in=c, dtype=np.int32,
+    )).lower(frames, carry).compile()
+    kernel = [line for line in compiled.as_text().splitlines()
+              if "tpu_custom_call" in line and "wf_tis" in line]
+    assert kernel and "s32[1,128,1024,8192]" in kernel[0]
+
+
+def test_region_loop_keeps_a_chips_h_in_place(one_chip, no_compile_cache):
+    """A chip's share of the 8192x8192x128 int32 H (32 bins, 8 GiB) is
+    answered for 288 fragments with no copy of H: a gather there has the
+    compiler lay H out again, which does not fit the chip."""
+    from repro.core import region_query as rq
+
+    H = jax.ShapeDtypeStruct((32, 8192, 8192), jnp.int32, sharding=one_chip)
+    rects = jax.ShapeDtypeStruct((288, 4), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(rq.regions_by_tiles).lower(H, rects).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
