@@ -64,6 +64,7 @@ from repro.core.hsource import (
     counting_d2h,
     to_host,
 )
+from repro.core.region_query import row_lattices
 
 REPRESENTATIONS = ("dense", "banded", "spilled", "sharded", "fused")
 
@@ -693,6 +694,20 @@ class MultiScaleQuery:
                 if rows else np.zeros((0,), np.int64))
 
 
+def _lattices(q, path: str) -> dict:
+    """The ``lattices`` attribute of q's ``engine.query`` span, where the
+    window program (``region_query._dense_windows``) answers q."""
+    if path == "rows":
+        return {}
+    if isinstance(q, (SlidingWindowQuery, LikelihoodQuery)):
+        windows = (q.window,)
+    elif isinstance(q, MultiScaleQuery) and q.windows:
+        windows = q.windows
+    else:
+        return {}
+    return {"lattices": max(row_lattices(w, q.stride) for w in windows)}
+
+
 @dataclasses.dataclass
 class EngineResult:
     """What ``HistogramEngine.run`` hands back.  ``d2h_bytes`` counts what
@@ -1083,7 +1098,11 @@ class HistogramEngine:
         query: ``path`` is ``compiled`` where a dense H answered through
         the compiled query programs, ``sharded`` where a bin-sharded H
         answered through them (and a shard_map for regions), ``rows``
-        where the corner-row protocol answered.
+        where the corner-row protocol answered.  On those two paths a
+        window, likelihood or multi-scale query's span also carries
+        ``lattices``: how many strided row lattices the window program
+        takes from H (1 shared, 2 split; the most over a search's
+        scales).
 
         A run whose H takes more than ``_ONE_H_DEVICE_SHARE`` of a
         device's memory takes one frame, and first waits for the answers
@@ -1121,7 +1140,7 @@ class HistogramEngine:
             results = []
             for q in queries:
                 with TraceAnnotation("engine.query", kind=type(q).__name__,
-                                     path=path):
+                                     path=path, **_lattices(q, path)):
                     results.append(q.apply(target))
             if one_h:
                 self._last_answers = results

@@ -25,12 +25,15 @@ program per query shape, so a query costs a dispatch or two, not one per
     sum, where every value is at most the window's area).  The regular
     window grid puts every corner of every window on a strided lattice
     of H, so the whole (n_rows, n_cols) field of Eq.-2 queries is
-    strided ``lax.slice`` calls (two row lattices, then four corner
-    lattices along the columns) combined elementwise:
-    strided loads, no index arrays, no gather.  Strided indexing
-    (``H[..., r::s, c::s]``) would not do: jax turns a strided index
-    into iota/mul/add index arrays and a ``gather``, which eagerly reads
-    one bin column per window corner.
+    strided ``lax.slice`` calls combined elementwise: the row lattices,
+    then the column lattices of those, and the four corners as
+    contiguous slices of them.  Where the stride divides the window
+    along an axis, that axis's top (left) and bottom (right) corners lie
+    on one lattice, so a dividing window reads H by one strided slice;
+    otherwise by two.  Strided loads, no index arrays, no gather.
+    Strided indexing (``H[..., r::s, c::s]``) would not do: jax turns a
+    strided index into iota/mul/add index arrays and a ``gather``, which
+    eagerly reads one bin column per window corner.
   * ``_score`` — ``metric(hists, target)``, ``metric`` static and the
     target traced.  Every representation scores through it, so a
     likelihood map is bit-identical whichever H answered.  It stays a
@@ -189,11 +192,13 @@ def _sliding_windows_gather(
 
 def _lattice(x, axis: int, start: int, n: int, s: int):
     """x[start + i·s] for i < n along ``axis``: one strided ``lax.slice``
-    (an empty lattice is a zero-size array)."""
+    (an empty lattice is a zero-size array, the whole axis is x itself)."""
     shape = list(x.shape)
     if n == 0:
         shape[axis] = 0
         return jnp.zeros(shape, x.dtype)
+    if start == 0 and n == shape[axis]:
+        return x
     starts = [0] * x.ndim
     strides = [1] * x.ndim
     starts[axis] = start
@@ -209,6 +214,56 @@ def _zero_first(x, axis: int):
     return jnp.concatenate([jnp.zeros(shape, x.dtype), x], axis=axis)
 
 
+def _shares_lattice(extent: int, stride: int) -> bool:
+    """Do a window's near and far corners along an axis lie on one
+    lattice?  They do when the stride divides the window's extent."""
+    return extent % stride == 0
+
+
+def row_lattices(window: tuple[int, int], stride: int) -> int:
+    """How many strided row lattices ``_dense_windows`` takes from H:
+    1 where the stride divides the window's height, else 2."""
+    return 1 if _shares_lattice(window[0], stride) else 2
+
+
+def _corner_lattices(x, axis: int, extent: int, n: int, s: int, *,
+                     zero: bool):
+    """The corner lattices of n windows of ``extent`` at stride s along
+    ``axis``: the near corners x[i·s - 1] (x[-1] the virtual zero) and
+    the far corners x[extent-1 + i·s], i < n.
+
+    Returns ``(lattices, near, far)``, ``near`` and ``far`` each a
+    (lattice index, start) pair for ``_corners``.  Where s divides the
+    extent both lie on one lattice, x[s-1 + i·s] for i < n - 1 +
+    extent/s, whose far corners start extent/s entries after the near
+    ones: x is read by one strided slice.  Otherwise they are two
+    lattices.  With ``zero`` the virtual zero is prepended to the
+    near corners' lattice and counted in the starts; without, the near
+    start is -1."""
+    if _shares_lattice(extent, s):
+        k = extent // s
+        lattices = [_lattice(x, axis, s - 1, n - 1 + k, s)]
+        near, far = (0, -1), (0, k - 1)
+    else:
+        lattices = [_lattice(x, axis, s - 1, n - 1, s),
+                    _lattice(x, axis, extent - 1, n, s)]
+        near, far = (0, -1), (1, 0)
+    if zero:                    # every start on lattice 0 moves up one
+        lattices[0] = _zero_first(lattices[0], axis)
+        near = (0, 0)
+        if far[0] == 0:
+            far = (0, far[1] + 1)
+    return lattices, near, far
+
+
+def _corners(x, axis: int, start: int, n: int):
+    """x[start : start + n] along ``axis``, where start -1 reads the
+    virtual zero before x[0]."""
+    if start < 0:
+        return _zero_first(_lattice(x, axis, 0, n - 1, 1), axis)
+    return _lattice(x, axis, start, n, 1)
+
+
 @functools.partial(jax.jit, static_argnames=("window", "stride"))
 def _dense_windows(
     H: jnp.ndarray, window: tuple[int, int], stride: int
@@ -219,33 +274,50 @@ def _dense_windows(
     every window on strided lattices of H itself:
 
       bottom-right  H[wh-1 + i·s, ww-1 + j·s]
-      top-right     H[s-1 + i·s,  ww-1 + j·s]   shifted down one row,
-                                                zero row prepended
-      (and symmetrically for the left corners)
+      top-right     H[i·s - 1,    ww-1 + j·s]   H(-1, ·) = 0
+      (and symmetrically for the left corners, on columns j·s - 1)
 
-    The row lattices are taken first, on H's row axis.  Their column axis
-    is then moved to the front, so the column lattices are strided slices
-    of a major axis, not of the minor (lane) axis: on a TPU v5e the 1080p
-    32-bin 64x64 stride-2 field took 15.3 ms with both strides on H's
-    last two axes and 3.75 ms this way.  The result is built as
-    (columns, bins, rows), which is the layout the TPU compiler gives
-    (rows, columns, bins), so the last moveaxis is free there.  The
-    virtual H(-1, ·) = H(·, -1) = 0 boundary is a one-element zero strip
-    prepended to the lattices.
+    The row lattices are taken first, on H's row axis: where the stride
+    divides the window's height the top and bottom rows lie on one
+    lattice, H[s-1 + i·s], wh/s entries apart, so H is read by one
+    strided slice; otherwise they are two lattices.  The column axis is
+    then moved to the front, so the column lattices are strided slices
+    of a major axis, not of the minor (lane) axis: on a TPU v5e the
+    1080p 32-bin 64x64 stride-2 field took 15.3 ms with both strides on
+    H's last two axes, 3.75 ms this way with two row lattices, and
+    1.66 ms with one.  The columns are taken the
+    same way, one lattice where the stride divides the window's width,
+    and the four corners are contiguous slices of the lattices.  The
+    result is built as (columns, bins, rows), which is the layout the
+    TPU compiler gives (rows, columns, bins), so the last moveaxis is
+    free there.
+
+    The virtual H(-1, ·) = H(·, -1) = 0 boundary is a one-element zero
+    strip: prepended to the column lattices, and to the top corners once
+    they are picked.  Prepended to the row lattice, which is the minor
+    axis after the move, the compiler for the v5e spends a pass over the
+    whole moved lattice on it.
     """
     h, w = H.shape[-2:]
     wh, ww = window
     s = stride
     n_r = (h - wh) // s + 1
     n_c = (w - ww) // s + 1
-    bottom = _lattice(H, -2, wh - 1, n_r, s)                 # (..., b, n_r, w)
-    top = _zero_first(_lattice(H, -2, s - 1, n_r - 1, s), -2)
-    bottom = jnp.moveaxis(bottom, -1, -3)                    # (..., w, b, n_r)
-    top = jnp.moveaxis(top, -1, -3)
-    d = _lattice(bottom, -3, ww - 1, n_c, s)                 # (..., n_c, b, n_r)
-    b = _lattice(top, -3, ww - 1, n_c, s)
-    c = _zero_first(_lattice(bottom, -3, s - 1, n_c - 1, s), -3)
-    a = _zero_first(_lattice(top, -3, s - 1, n_c - 1, s), -3)
+    rows, top, bottom = _corner_lattices(H, -2, wh, n_r, s, zero=False)
+    rows = [jnp.moveaxis(r, -1, -3) for r in rows]           # (..., w, b, *)
+    cols = [_corner_lattices(r, -3, ww, n_c, s, zero=True) for r in rows]
+
+    def corner(row, side):                                   # (..., n_c, b, n_r)
+        lattices, *sides = cols[row[0]]
+        lattice, start = sides[side]
+        x = _corners(lattices[lattice], -3, start, n_c)
+        return _corners(x, -1, row[1], n_r)
+
+    left, right = 0, 1
+    d = corner(bottom, right)
+    b = corner(top, right)
+    c = corner(bottom, left)
+    a = corner(top, left)
     # Same association order as the gather path (d - b - c + a) so the
     # fp32 arithmetic is bit-identical, not just allclose.  An int32 H's
     # field becomes fp32 only after the sum (a no-op for an fp32 H).

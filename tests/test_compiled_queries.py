@@ -6,9 +6,11 @@ On a dense H, ``sliding_window_histograms`` is one program
 strides, a likelihood map adds one scoring program (``_score``), and
 ``region_histogram`` is one program (``_dense_regions``) per rect count.
 These tests check the lowering (no ``gather`` on the window path, one
+strided slice of H where the stride divides the window's height, one
 compile per rect count), that the programs are bit-exact against the
-eager per-window gather and a NumPy four-corner sum, and the ``path``
-attribute of the engine's ``engine.query`` span.
+eager per-window gather, for a float32 H and an int32 H past 2**24, and
+a NumPy four-corner sum, and the ``path`` and ``lattices`` attributes of
+the engine's ``engine.query`` span.
 """
 
 import jax
@@ -29,6 +31,15 @@ def _h(rng, lead=()):
     Hs = jnp.stack([integral_histogram_ref(jnp.asarray(im), BINS)
                     for im in imgs])
     return Hs.reshape(lead + (BINS, H_, W_))
+
+
+def _slices_of_h(jaxpr):
+    """The ``slice`` equations of the window program that read its H."""
+    [program] = [e for e in jaxpr.eqns
+                 if e.params.get("name") == "_dense_windows"]
+    inner = program.params["jaxpr"].jaxpr
+    return [e for e in inner.eqns
+            if e.primitive.name == "slice" and e.invars[0] is inner.invars[0]]
 
 
 def _primitives(jaxpr):
@@ -57,9 +68,17 @@ def test_dense_window_path_has_no_gather(rng, lead):
         assert "gather" not in prims
         # two row lattices, then four corner lattices along the columns
         assert prims.count("slice") == 6
+        assert len(_slices_of_h(jaxpr)) == 2
     # a dense likelihood map is two programs: window histograms, then score
     assert [e.params["name"] for e in lmap.jaxpr.eqns] == [
         "_dense_windows", "_score"]
+    # where the stride divides the window, both row lattices are one:
+    # H is read by a single slice, strided along its row axis
+    shared = jax.make_jaxpr(
+        lambda H: rq.sliding_window_histograms(H, (8, 8), 2))(H).jaxpr
+    assert "gather" not in list(_primitives(shared))
+    [read] = _slices_of_h(shared)
+    assert read.params["strides"] == (1,) * (H.ndim - 2) + (2, 1)
 
 
 def test_dense_region_program_compiles_once_per_rect_count(rng):
@@ -86,15 +105,27 @@ def test_dense_region_program_compiles_once_per_rect_count(rng):
     ((24, 30), 4),      # ... at a stride past the frame
     ((25, 30), 1),      # window taller than the frame: empty
     ((24, 31), 2),      # window wider than the frame: empty
+    ((8, 8), 2),        # the stride divides both sides: one lattice each
+    ((8, 12), 4),       # ... with rows and columns wh/s, ww/s apart
+    ((4, 4), 4),        # the stride equal to the window
+    ((8, 10), 4),       # the stride divides the height only
+    ((9, 8), 4),        # the stride divides the width only
 ])
 def test_compiled_windows_bit_exact_vs_gather(rng, lead, window, stride):
     H = _h(rng, lead)
-    got = rq.sliding_window_histograms(H, window, stride)
-    want = rq.sliding_window_histograms(H, window, stride, impl="gather")
     n_r = max((H_ - window[0]) // stride + 1, 0)
     n_c = max((W_ - window[1]) // stride + 1, 0)
-    assert got.shape == lead + (n_r, n_c, BINS)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the same frames with 2**24 + 1 more counts of every bin at pixel
+    # (0, 0): every entry of the int32 H lies past float32's exact range
+    for H in (H, H.astype(jnp.int32) + (1 << 24) + 1):
+        got = rq.sliding_window_histograms(H, window, stride)
+        want = rq.sliding_window_histograms(H, window, stride,
+                                            impl="gather")
+        assert got.shape == lead + (n_r, n_c, BINS)
+        assert got.dtype == jnp.float32
+        # the int32 field is summed exactly, then rounded once to fp32
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(want).astype(np.float32))
 
 
 def _numpy_regions(Hn, rects):
@@ -129,19 +160,20 @@ def test_compiled_regions_bit_exact_vs_numpy(rng, n_rects):
 
 
 # ---------------------------------------------------------------------------
-# the engine.query span says which path answered
+# the engine.query span says which path answered, and how many row
+# lattices the window program took
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("budget,path", [(None, "compiled"),
-                                         (4 * BINS * W_ * 8, "rows")],
-                         ids=["dense", "banded"])
-def test_engine_query_span_names_its_path(rng, monkeypatch, budget, path):
+@pytest.fixture
+def query_spans(monkeypatch):
+    """The stats of every ``engine.query`` span the engine opens."""
     from repro.core import engine as engine_mod
 
     spans = []
 
     class Recorder:
         def __init__(self, name, **stats):
-            spans.append((name, stats))
+            if name == "engine.query":
+                spans.append(stats)
 
         def __enter__(self):
             return self
@@ -153,6 +185,15 @@ def test_engine_query_span_names_its_path(rng, monkeypatch, budget, path):
             pass
 
     monkeypatch.setattr(engine_mod, "TraceAnnotation", Recorder)
+    return spans
+
+
+@pytest.mark.parametrize("budget,path", [(None, "compiled"),
+                                         (4 * BINS * W_ * 8, "rows")],
+                         ids=["dense", "banded"])
+def test_engine_query_span_names_its_path(rng, query_spans, budget, path):
+    from repro.core import engine as engine_mod
+
     eng = engine_mod.HistogramEngine(BINS, backend="jnp",
                                      memory_budget_bytes=budget)
     frame = rng.integers(0, 256, (H_, W_), dtype=np.uint8)
@@ -161,5 +202,39 @@ def test_engine_query_span_names_its_path(rng, monkeypatch, budget, path):
                           engine_mod.RegionQuery([[0, 0, 9, 9]])])
     assert out.plan.representation == ("dense" if budget is None
                                        else "banded")
-    queries = [stats for name, stats in spans if name == "engine.query"]
-    assert [q["path"] for q in queries] == [path, path]
+    assert [q["path"] for q in query_spans] == [path, path]
+    # only the window program's answers count its row lattices
+    assert [q.get("lattices") for q in query_spans] == (
+        [1, None] if path == "compiled" else [None, None])
+
+
+@pytest.mark.parametrize("kind,window,stride,lattices", [
+    ("LikelihoodQuery", (48, 48), 4, 1),
+    ("LikelihoodQuery", (8, 10), 3, 2),
+    ("SlidingWindowQuery", (8, 10), 4, 1),
+    ("MultiScaleQuery", ((16, 16), (9, 9)), 4, 2),
+])
+def test_engine_query_span_counts_row_lattices(rng, query_spans, kind,
+                                               window, stride, lattices):
+    from repro.core import engine as engine_mod
+
+    target = np.ones((BINS,), np.float32)
+    query = {
+        "LikelihoodQuery": lambda: engine_mod.LikelihoodQuery(
+            target, window, stride=stride),
+        "SlidingWindowQuery": lambda: engine_mod.SlidingWindowQuery(
+            window, stride),
+        "MultiScaleQuery": lambda: engine_mod.MultiScaleQuery(
+            target, window, stride=stride),
+    }[kind]()
+    eng = engine_mod.HistogramEngine(BINS, backend="jnp")
+    frame = rng.integers(0, 256, (64, 96), dtype=np.uint8)
+    # fragments on every other row: too many corner rows to fuse, so H
+    # is dense and the window program answers
+    rows = engine_mod.RegionQuery([[r, 0, r, 9] for r in range(0, 64, 2)])
+    out = eng.run(frame, [query, rows])
+    assert out.plan.representation == "dense"
+    window_span, region_span = query_spans
+    assert (window_span["kind"], window_span["path"]) == (kind, "compiled")
+    assert window_span["lattices"] == lattices
+    assert "lattices" not in region_span

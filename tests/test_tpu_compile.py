@@ -12,6 +12,7 @@ fixture, so importing this file never loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -89,15 +90,47 @@ def test_kernel_compiles_for_v5e(kernel, shape, one_chip, no_compile_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _reads_of_h(hlo: str) -> list:
+    """The instructions of a compiled program's entry computation that
+    take its first parameter, H, as an operand."""
+    entry = hlo[hlo.index("\nENTRY"):]
+    [h] = re.findall(r"(%[\w.]+) = \S+ parameter\(0\)", entry)
+    return re.findall(r"= \S+ ([\w-]+)\([^)]*" + re.escape(h) + r"[,)]",
+                      entry)
+
+
 def test_dense_window_program_compiles_for_v5e(one_chip, no_compile_cache):
     """The 1080p 64x64 stride-2 window field compiles to strided slices:
-    no gather in the chip's program."""
+    no gather in the chip's program, and H is read by one slice (the
+    stride divides the window, so both row lattices are one)."""
     from repro.core import region_query as rq
 
     H = jax.ShapeDtypeStruct((32, 1080, 1920), jnp.float32,
                              sharding=one_chip)
     compiled = rq._dense_windows.lower(H, window=(64, 64), stride=2).compile()
     assert "gather" not in compiled.as_text()
+    assert _reads_of_h(compiled.as_text()) == ["slice"]
+
+
+def test_bin_sharded_window_program_reads_h_once(topo, no_compile_cache):
+    """The paper's 8192x8192 int32 H with its 128 bins over the four
+    chips of a v5e 2x2: the stride-16 64x64 window program reads each
+    chip's share of H by one slice and moves nothing between chips."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import region_query as rq
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("model",))
+    H = jax.ShapeDtypeStruct(
+        (1, 128, 8192, 8192), jnp.int32,
+        sharding=NamedSharding(mesh, P(None, "model", None, None)))
+    hlo = rq._dense_windows.lower(H, window=(64, 64), stride=16) \
+        .compile().as_text()
+    assert "s32[1,32,8192,8192]" in hlo          # each chip holds 32 bins
+    assert _reads_of_h(hlo) == ["slice"]
+    for collective in ("all-gather", "all-to-all", "all-reduce",
+                       "collective-permute", "reduce-scatter"):
+        assert collective not in hlo
 
 
 def test_int32_wf_tis_compiles_for_v5e(one_chip, no_compile_cache):
